@@ -34,7 +34,9 @@ def run(quick: bool = True):
         # store never allocates) so it is cheap enough for the quick pass,
         # and its state-residency verdict is a row we want tracked per PR
         cmd += ["--engine", "dense,sampled", "--codec", "none"]
-    env = dict(os.environ)
+    # the audit traces on host devices: the child must never claim an
+    # accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(_REPO, "src"), env.get("PYTHONPATH"))
         if p)

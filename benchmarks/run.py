@@ -12,6 +12,8 @@ import json
 import sys
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -22,6 +24,7 @@ def main(argv=None) -> None:
                     help="also dump rows to a BENCH_*.json-style file")
     args = ap.parse_args(argv)
     quick = not args.full
+    enable_compile_cache()
 
     from benchmarks import (accuracy, analysis_audit, chaos_soak, comm_time,
                             compression_sweep, kernel_bench, lq_sweep,

@@ -3,8 +3,9 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # ^ MUST precede any jax-importing import (dryrun.py pattern): mesh-engine
-#   programs trace shard_map bodies against an 8-way data mesh.
+#   programs trace shard_map bodies against an 8-way host data mesh.
 
 """Audit every registered protocol's compiled programs on both engines.
 

@@ -51,10 +51,7 @@ import json
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-try:
-    from jax.extend.core import Literal, Var  # noqa: F401 — jax >= 0.4.33
-except ImportError:  # pragma: no cover — older layouts
-    from jax.core import Literal, Var  # type: ignore  # noqa: F401
+from jax.extend.core import Literal, Var  # noqa: F401
 
 from repro.analysis.findings import ERROR, INFO, Finding
 from repro.analysis.walker import _open, sub_jaxprs

@@ -43,10 +43,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Callable, Iterator, Optional, Tuple
 
-try:  # jax >= 0.4.x keeps these importable from jax.core
-    from jax.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover — future relocations
-    from jax.extend.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 #: primitives whose sub-jaxpr bodies execute once per loop iteration

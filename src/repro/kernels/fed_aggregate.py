@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.backend import default_interpret
+from repro.kernels.backend import F32_CONTRACT, default_interpret
 
 DEFAULT_BLOCK_D = 2048
 
@@ -28,7 +28,8 @@ def _fed_aggregate_kernel(w_ref, x_ref, o_ref):
     acc = jax.lax.dot_general(
         w_ref[...], x,
         dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 

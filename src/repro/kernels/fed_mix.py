@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import default_interpret
+from repro.kernels.backend import F32_CONTRACT, default_interpret
 
 DEFAULT_BLOCK_R = 128
 DEFAULT_BLOCK_D = 2048
@@ -49,10 +49,12 @@ def _fed_mix_kernel(mn_ref, mo_ref, xn_ref, xo_ref, o_ref, acc_scr, *,
     dims = (((1,), (0,)), ((), ()))
     acc = jax.lax.dot_general(
         mn_ref[...], xn_ref[...].astype(jnp.float32),
-        dimension_numbers=dims, preferred_element_type=jnp.float32)
+        dimension_numbers=dims, preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     acc = acc + jax.lax.dot_general(
         mo_ref[...], xo_ref[...].astype(jnp.float32),
-        dimension_numbers=dims, preferred_element_type=jnp.float32)
+        dimension_numbers=dims, preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     acc_scr[...] += acc
 
     @pl.when(ik == nk - 1)

@@ -6,11 +6,17 @@ the int8-wire form of ``kernels.fed_mix``: X_new arrives as the
 ``Int8Codec`` record — int8 values [D, Pq] plus one float32 absmax scale
 per ``chunk`` consecutive params [D, Pq/chunk] — and is dequantized
 *inline in the MXU contraction loop*. Each grid step loads an int8
-[bk, bd] tile (4X less HBM->VMEM traffic than f32), expands its
-[bk, bd/chunk] scale tile across lanes, multiplies, and feeds the MXU —
-so the dense path never materializes a full-precision copy of the
-quantized client buffer anywhere: the f32 tile lives only in VMEM
-registers for the duration of one contraction step.
+[bk, bd] tile (4X less HBM->VMEM traffic than f32) and its [bk, bd/chunk]
+scale tile, broadcasts each scale column across its ``chunk`` lanes,
+multiplies, and feeds the MXU — so the dense path never materializes a
+full-precision copy of the quantized client buffer in HBM: the f32 tile
+lives only in a VMEM scratch for the duration of one contraction step.
+
+The scales are handed to the kernel as [Pq/block_d, D, block_d/chunk]:
+a Mosaic block's last dim must be a multiple of 128 lanes or the whole
+array dim, and a [bk, bd/chunk] window of the flat [D, Pq/chunk] layout
+is neither. Splitting the scale columns per param tile makes each
+block's last dim the whole (leading-squeezed) array dim.
 
 Grid/accumulator structure is identical to ``fed_mix`` (one grid step per
 (D-row-block, param-tile, K-block), two MXU contractions into a single f32
@@ -27,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import default_interpret
+from repro.kernels.backend import F32_CONTRACT, default_interpret
 
 DEFAULT_BLOCK_R = 128
 DEFAULT_BLOCK_D = 2048
@@ -35,30 +41,31 @@ DEFAULT_BLOCK_K = 256
 
 
 def _fed_mix_q_kernel(mn_ref, mo_ref, qn_ref, sc_ref, xo_ref, o_ref,
-                      acc_scr, *, nk: int, chunk: int):
+                      acc_scr, xn_scr, *, nk: int, chunk: int):
     # mn/mo: [br, bk] f32; qn: [bk, bd] int8; sc: [bk, bd/chunk] f32;
-    # xo: [bk, bd]; o: [br, bd]; acc: [br, bd] f32
+    # xo: [bk, bd]; o: [br, bd]; acc: [br, bd] f32; xn: [bk, bd] f32
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # inline dequant: expand the per-chunk scales across their lanes and
-    # multiply — the f32 tile exists only in VMEM for this grid step
-    q = qn_ref[...].astype(jnp.float32)
-    bk, bd = q.shape
-    sc = sc_ref[...]
-    scale = jnp.broadcast_to(sc[:, :, None], (bk, bd // chunk, chunk))
-    xn = q * scale.reshape(bk, bd)
+    # inline dequant, one chunk of lanes at a time: its scale column
+    # broadcasts across the chunk — the f32 tile exists only in VMEM
+    for c in range(xn_scr.shape[1] // chunk):
+        cols = pl.ds(c * chunk, chunk)
+        xn_scr[:, cols] = (qn_ref[:, cols].astype(jnp.float32)
+                           * sc_ref[:, c:c + 1])
 
     dims = (((1,), (0,)), ((), ()))
     acc = jax.lax.dot_general(
-        mn_ref[...], xn,
-        dimension_numbers=dims, preferred_element_type=jnp.float32)
+        mn_ref[...], xn_scr[...],
+        dimension_numbers=dims, preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     acc = acc + jax.lax.dot_general(
         mo_ref[...], xo_ref[...].astype(jnp.float32),
-        dimension_numbers=dims, preferred_element_type=jnp.float32)
+        dimension_numbers=dims, preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     acc_scr[...] += acc
 
     @pl.when(ik == nk - 1)
@@ -108,7 +115,9 @@ def fed_mix_q(m_new: jnp.ndarray, m_old: jnp.ndarray,
     mn = jnp.pad(m_new.astype(jnp.float32), ((0, dpr - d), (0, dpk - d)))
     mo = jnp.pad(m_old.astype(jnp.float32), ((0, dpr - d), (0, dpk - d)))
     qn = jnp.pad(q_new, ((0, dpk - d), (0, pad_p)))
+    nc = bd // chunk                      # scale columns per param tile
     sc = jnp.pad(scales, ((0, dpk - d), (0, pad_p // chunk)))
+    sc = sc.reshape(dpk, pp // bd, nc).transpose(1, 0, 2)
     xo = jnp.pad(x_old, ((0, dpk - d), (0, pp - p)))
     nk = dpk // bk
     out = pl.pallas_call(
@@ -119,11 +128,12 @@ def fed_mix_q(m_new: jnp.ndarray, m_old: jnp.ndarray,
             pl.BlockSpec((br, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((br, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bd), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk, bd // chunk), lambda i, j, k: (k, j)),
+            pl.BlockSpec((None, bk, nc), lambda i, j, k: (j, k, 0)),
             pl.BlockSpec((bk, bd), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((br, bd), lambda i, j, k: (i, j)),
-        scratch_shapes=[pltpu.VMEM((br, bd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((br, bd), jnp.float32),
+                        pltpu.VMEM((bk, bd), jnp.float32)],
         interpret=interpret,
     )(mn, mo, qn, sc, xo)
     return out[:d, :p]

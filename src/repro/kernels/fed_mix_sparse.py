@@ -13,12 +13,12 @@ registered protocol's structure is one of two ``MixingSpec`` forms
 
   lowered as ONE pass over X: a per-cluster segment reduce (the weights are
   folded into two skinny one-hot matrices, so the reduce is an
-  ``[Lp, bk] @ [bk, bd]`` MXU contraction accumulated over D-blocks — the
+  ``[L, bk] @ [bk, bd]`` MXU contraction accumulated over D-blocks — the
   fed_mix K-loop pattern with L rows instead of D) followed by a
-  gather-broadcast back to member rows (``[br, Lp] @ [Lp, bd]``). Total
-  O(D·Lp·P) MXU FLOPs with Lp = L rounded up to one lane tile — for
-  L ≪ D this is the O(D·P) fast path (at D=4096, L=8: ~32X fewer FLOPs
-  than the dense kernel, and no [D, D] operand ever exists).
+  gather-broadcast back to member rows (``[br, L] @ [L, bd]``). Total
+  O(D·L·P) MXU FLOPs — for L ≪ D this is the O(D·P) fast path (at
+  D=4096, L=8: ~340X fewer FLOPs than the dense kernel, and no [D, D]
+  operand ever exists).
 
 * ``fed_mix_matching`` — permutation form (gossip's two ring phases, one
   random perfect matching for ``gossip_async``): straggler-substitute
@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import default_interpret
+from repro.kernels.backend import F32_CONTRACT, default_interpret
 
 DEFAULT_BLOCK_R = 256
 DEFAULT_BLOCK_D = 512
@@ -60,10 +60,12 @@ def _segment_reduce_kernel(cn_ref, co_ref, xn_ref, xo_ref, seg_ref, acc_scr,
     dims = (((1,), (0,)), ((), ()))
     acc = jax.lax.dot_general(
         cn_ref[...], xn_ref[...].astype(jnp.float32),
-        dimension_numbers=dims, preferred_element_type=jnp.float32)
+        dimension_numbers=dims, preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     acc = acc + jax.lax.dot_general(
         co_ref[...], xo_ref[...].astype(jnp.float32),
-        dimension_numbers=dims, preferred_element_type=jnp.float32)
+        dimension_numbers=dims, preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT)
     acc_scr[...] += acc
 
     @pl.when(ik == nk - 1)
@@ -76,7 +78,8 @@ def _gather_broadcast_kernel(c_ref, seg_ref, o_ref):
     o_ref[...] = jax.lax.dot_general(
         c_ref[...], seg_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        preferred_element_type=jnp.float32,
+        precision=F32_CONTRACT).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -92,34 +95,37 @@ def fed_mix_segment(cluster_ids: jnp.ndarray, w_new: jnp.ndarray,
     """cluster_ids [D] i32; w_new/w_old [D]; x_new/x_old [D, P] -> [D, P].
 
     Structured-sparse mixing for cluster-segment specs, in x_new.dtype with
-    f32 accumulation. L (``num_segments``) is padded to one 128-lane tile so
-    both contractions are MXU-shaped; D is padded to the row/K blocks and P
-    to ``block_d`` (zero padding contributes exactly 0 to the sums). The
-    dense [D, D] operator is never formed.
+    f32 accumulation. No operand is padded to the tiles when it need not
+    be: the [L, P] segment sums keep exactly L rows, D stays unpadded while
+    it fits one row/K block (a block spanning a whole dim is always legal),
+    and the last ``block_d`` param tile is ragged — every column is mixed
+    independently, so its out-of-range lanes only reach output lanes that
+    are never stored. At LM widths a padded copy of a [D, P] operand is
+    gigabytes. D beyond one block is zero-padded (zero rows contribute
+    exactly 0 to the sums). The dense [D, D] operator is never formed.
     """
     interpret = default_interpret(interpret)
     d, p = x_new.shape
-    lp = ((max(1, num_segments) + 127) // 128) * 128
-    br = min(block_r, -(-d // 8) * 8)
-    bk = min(block_k, -(-d // 8) * 8)
+    lp = max(1, num_segments)
+    br = d if d <= block_r else block_r
+    bk = d if d <= block_k else block_k
     dpr = d + (-d) % br                   # gather-phase row padding
     dpk = d + (-d) % bk                   # reduce-phase contraction padding
-    pad_p = (-p) % block_d
-    pp = p + pad_p
+    n_p = pl.cdiv(p, block_d)
 
-    onehot = jax.nn.one_hot(cluster_ids, lp, dtype=jnp.float32)     # [D, Lp]
+    onehot = jax.nn.one_hot(cluster_ids, lp, dtype=jnp.float32)     # [D, L]
     cn = jnp.pad((onehot * w_new.astype(jnp.float32)[:, None]).T,
-                 ((0, 0), (0, dpk - d)))                            # [Lp, Dk]
+                 ((0, 0), (0, dpk - d)))                            # [L, Dk]
     co = jnp.pad((onehot * w_old.astype(jnp.float32)[:, None]).T,
                  ((0, 0), (0, dpk - d)))
-    xn = jnp.pad(x_new, ((0, dpk - d), (0, pad_p)))
-    xo = jnp.pad(x_old, ((0, dpk - d), (0, pad_p)))
+    xn = jnp.pad(x_new, ((0, dpk - d), (0, 0))) if dpk > d else x_new
+    xo = jnp.pad(x_old, ((0, dpk - d), (0, 0))) if dpk > d else x_old
     nk = dpk // bk
 
     seg = pl.pallas_call(
         functools.partial(_segment_reduce_kernel, nk=nk),
-        out_shape=jax.ShapeDtypeStruct((lp, pp), jnp.float32),
-        grid=(pp // block_d, nk),
+        out_shape=jax.ShapeDtypeStruct((lp, p), jnp.float32),
+        grid=(n_p, nk),
         in_specs=[
             pl.BlockSpec((lp, bk), lambda j, k: (0, k)),
             pl.BlockSpec((lp, bk), lambda j, k: (0, k)),
@@ -131,11 +137,11 @@ def fed_mix_segment(cluster_ids: jnp.ndarray, w_new: jnp.ndarray,
         interpret=interpret,
     )(cn, co, xn, xo)
 
-    c_rows = jnp.pad(onehot, ((0, dpr - d), (0, 0)))                # [Dr, Lp]
+    c_rows = jnp.pad(onehot, ((0, dpr - d), (0, 0)))                # [Dr, L]
     out = pl.pallas_call(
         _gather_broadcast_kernel,
-        out_shape=jax.ShapeDtypeStruct((dpr, pp), x_new.dtype),
-        grid=(dpr // br, pp // block_d),
+        out_shape=jax.ShapeDtypeStruct((dpr, p), x_new.dtype),
+        grid=(dpr // br, n_p),
         in_specs=[
             pl.BlockSpec((br, lp), lambda i, j: (i, 0)),
             pl.BlockSpec((lp, block_d), lambda i, j: (0, j)),
@@ -143,7 +149,7 @@ def fed_mix_segment(cluster_ids: jnp.ndarray, w_new: jnp.ndarray,
         out_specs=pl.BlockSpec((br, block_d), lambda i, j: (i, j)),
         interpret=interpret,
     )(c_rows, seg)
-    return out[:d, :p]
+    return out[:d] if dpr > d else out
 
 
 def _pair_average_kernel(a_ref, b_ref, o_ref):
@@ -165,14 +171,15 @@ def fed_mix_matching(perms: jnp.ndarray, survive: jnp.ndarray,
     average every row with its partner row (byes average with themselves —
     exact in float). The per-stage row gather is an XLA take (bandwidth-
     bound, no block structure to exploit); the VPU halving-add is the
-    Pallas-tiled part. Everything is O(S·D·P) — no [D, D] operator.
+    Pallas-tiled part. Everything is O(S·D·P) — no [D, D] operator. As in
+    ``fed_mix_segment``, D is padded only past one row block and the last
+    param tile is ragged (the halving-add is elementwise).
     """
     interpret = default_interpret(interpret)
     d, p = x_new.shape
-    br = min(block_r, -(-d // 8) * 8)
+    br = d if d <= block_r else block_r
     pad_r = (-d) % br
-    pad_p = (-p) % block_d
-    grid = ((d + pad_r) // br, (p + pad_p) // block_d)
+    grid = ((d + pad_r) // br, pl.cdiv(p, block_d))
 
     def avg(a, b):
         return pl.pallas_call(
@@ -190,9 +197,10 @@ def fed_mix_matching(perms: jnp.ndarray, survive: jnp.ndarray,
            + (1.0 - s) * x_old.astype(jnp.float32))
     # pad ONCE around the whole stage loop (padded rows self-average and
     # stay zero: perms only address rows < d, extended with the identity)
-    eff = jnp.pad(eff, ((0, pad_r), (0, pad_p)))
+    if pad_r:
+        eff = jnp.pad(eff, ((0, pad_r), (0, 0)))
     tail = jnp.arange(d, d + pad_r, dtype=perms.dtype)
     for i in range(perms.shape[0]):
         perm_p = jnp.concatenate([perms[i], tail])
         eff = avg(eff, jnp.take(eff, perm_p, axis=0))
-    return eff[:d, :p].astype(x_new.dtype)
+    return eff[:d].astype(x_new.dtype)

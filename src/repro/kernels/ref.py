@@ -9,10 +9,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import F32_CONTRACT
+
 
 def fed_aggregate_ref(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """x: [N, D]; w: [N] -> [D] (f32 accumulate, cast back)."""
-    out = jnp.einsum("n,nd->d", w.astype(jnp.float32), x.astype(jnp.float32))
+    out = jnp.einsum("n,nd->d", w.astype(jnp.float32), x.astype(jnp.float32),
+                     precision=F32_CONTRACT)
     return out.astype(x.dtype)
 
 
@@ -23,8 +26,10 @@ def fed_mix_ref(m_new: jnp.ndarray, m_old: jnp.ndarray,
     The dense mixing operator f_out = M_new @ f_new + M_old @ f_old on
     flat-packed client params (f32 accumulate, cast back to x_new.dtype).
     """
-    out = m_new.astype(jnp.float32) @ x_new.astype(jnp.float32)
-    out = out + m_old.astype(jnp.float32) @ x_old.astype(jnp.float32)
+    out = jnp.matmul(m_new.astype(jnp.float32), x_new.astype(jnp.float32),
+                     precision=F32_CONTRACT)
+    out = out + jnp.matmul(m_old.astype(jnp.float32),
+                           x_old.astype(jnp.float32), precision=F32_CONTRACT)
     return out.astype(x_new.dtype)
 
 
@@ -85,8 +90,9 @@ def fed_mix_q_ref(m_new: jnp.ndarray, m_old: jnp.ndarray,
     n = x_old.shape[1]
     v = q_new.astype(jnp.float32).reshape(d, -1, chunk)
     xn = (v * scales.astype(jnp.float32)[..., None]).reshape(d, -1)[:, :n]
-    out = m_new.astype(jnp.float32) @ xn
-    out = out + m_old.astype(jnp.float32) @ x_old.astype(jnp.float32)
+    out = jnp.matmul(m_new.astype(jnp.float32), xn, precision=F32_CONTRACT)
+    out = out + jnp.matmul(m_old.astype(jnp.float32),
+                           x_old.astype(jnp.float32), precision=F32_CONTRACT)
     return out.astype(x_old.dtype if out_dtype is None else out_dtype)
 
 

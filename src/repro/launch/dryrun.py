@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
-# ^ MUST precede any jax-importing import: jax locks the device count at init.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# ^ MUST precede any jax-importing import: jax locks the device count at init,
+#   and the 512 virtual devices are host devices — never the accelerator.
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape) entry point
 against the production mesh and extract memory/cost/collective analyses.

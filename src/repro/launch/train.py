@@ -31,6 +31,7 @@ from repro.config import FLConfig, TrainConfig
 from repro.configs import get_config
 from repro.core.fedp2p import broadcast_to_clients
 from repro.data.lm import token_stream_batches
+from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models.model import build_model
 from repro.protocols.engine import MeshEngine
@@ -70,6 +71,18 @@ def run_lm_training(arch: str, *, steps: int = 100, batch: int = 8,
             "first_loss": losses[0], "steps": steps}
 
 
+def stage_rounds(streams, rounds: int, local_steps: int
+                 ) -> Dict[str, np.ndarray]:
+    """Draw ``rounds`` rounds of batches from per-client token streams (one
+    ``token_stream_batches`` iterator per client) in the layout
+    ``MeshEngine.run_rounds`` takes: leaves [rounds, D, local_steps, B, S]."""
+    staged = [[[next(s) for _ in range(local_steps)] for s in streams]
+              for _ in range(rounds)]
+    return {k: np.stack([[np.stack([b[k] for b in client]) for client in rnd]
+                         for rnd in staged])
+            for k in ("tokens", "labels")}
+
+
 def run_federated_training(arch: str, *, rounds: int = 20,
                            num_clients: int = 4, num_clusters: int = 2,
                            local_steps: int = 4, batch: int = 4,
@@ -77,13 +90,17 @@ def run_federated_training(arch: str, *, rounds: int = 20,
                            codec: str = "none",
                            sync_period: int = 1, straggler_rate: float = 0.0,
                            lr: float = 5e-3, seed: int = 0,
-                           counts=None, verbose: bool = True) -> Dict:
+                           counts=None, reduced: bool = True,
+                           verbose: bool = True) -> Dict:
     """Paper protocol over LM clients with heterogeneous token streams.
     ``algorithm`` is any ``repro.protocols`` registry name; ``counts``
     carries non-uniform per-client |D_i| weights onto the mesh path;
     ``codec`` is any ``repro.compression`` name — the lossy wire format
-    of every exchanged update."""
-    cfg = get_config(arch).reduced(num_layers=2, max_d_model=128)
+    of every exchanged update. ``reduced=False`` trains the architecture
+    at its published width and depth."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced(num_layers=2, max_d_model=128)
     model = build_model(cfg)
     fl = FLConfig(num_clusters=num_clusters, lr=lr,
                   straggler_rate=straggler_rate, sync_period=sync_period,
@@ -110,11 +127,7 @@ def run_federated_training(arch: str, *, rounds: int = 20,
     cstate = None
     while done < rounds:
         n = min(chunk_rounds, rounds - done)
-        staged = [[[next(streams[c]) for _ in range(local_steps)]
-                   for c in range(num_clients)] for _ in range(n)]
-        bt = {k: jnp.asarray(np.stack([[np.stack([s[k] for s in client])
-                                        for client in rnd] for rnd in staged]))
-              for k in ("tokens", "labels")}
+        bt = jax.tree.map(jnp.asarray, stage_rounds(streams, n, local_steps))
         key, kc = jax.random.split(key)
         if stateful:
             f_params, loss_buf, cstate = engine.run_rounds(
@@ -145,6 +158,7 @@ def main():
     ap.add_argument("--full", action="store_true", help="full (unreduced) config")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "lm":
         out = run_lm_training(args.arch, steps=args.steps,
                               reduced=not args.full, ckpt_dir=args.ckpt_dir)
@@ -152,7 +166,8 @@ def main():
         out = run_federated_training(args.arch, rounds=args.rounds,
                                      algorithm=args.algorithm,
                                      codec=args.codec,
-                                     straggler_rate=args.straggler_rate)
+                                     straggler_rate=args.straggler_rate,
+                                     reduced=not args.full)
     print(f"loss {out['first_loss']:.4f} -> {out['final_loss']:.4f}")
 
 

@@ -225,8 +225,7 @@ def moe_ffn_ep(p: Dict, x: jnp.ndarray, cfg: ModelConfig, info
     shared = p.get("shared")
     shared_spec = (jax.tree.map(lambda _: P(), shared)
                    if shared is not None else None)
-    from repro.sharding.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=info.mesh,
         in_specs=(P(), P(tp, None, None),
                   P(tp, None, None) if gated else P(),

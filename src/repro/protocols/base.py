@@ -56,7 +56,6 @@ from repro.core.topology import Topology
 from repro.kernels import ops as kernel_ops
 from repro.protocols.context import (  # noqa: F401
     RoundContext, concrete_cluster_ids, make_context)
-from repro.sharding.compat import shard_map
 
 
 class Protocol:
@@ -242,10 +241,10 @@ class Protocol:
         axes = names if len(names) > 1 else names[0]
         spec = jax.tree.map(lambda _: P(axes), f_new)
         sspec = P(axes)
-        fn = shard_map(local_fn, mesh=mesh_info.mesh,
-                       in_specs=(spec, spec, sspec, sspec)
-                                + (P(),) * len(extras),
-                       out_specs=spec, check_vma=False)
+        fn = jax.shard_map(local_fn, mesh=mesh_info.mesh,
+                           in_specs=(spec, spec, sspec, sspec)
+                                    + (P(),) * len(extras),
+                           out_specs=spec, check_vma=False)
         return fn(f_new, f_old, ctx.survive, ctx.counts, *extras)
 
     @staticmethod

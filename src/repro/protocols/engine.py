@@ -620,6 +620,10 @@ class SampledEngine:
         #: default ``fl.prefetch_timeout`` (0 = forever)
         pt = fl.prefetch_timeout if prefetch_timeout is None else prefetch_timeout
         self.prefetch_timeout = float(pt) if pt else None
+        #: prefetches abandoned for a synchronous gather over this engine's
+        #: life, counted under every fault plan (None included): a healthy
+        #: pipeline keeps it at 0
+        self.prefetch_fallbacks = 0
         #: D — enrolled population; K — active window per round
         self.num_enrolled = fl.enrolled
         self.window = validate_participation(fl, proto)
@@ -1051,6 +1055,7 @@ class SampledEngine:
         try:
             return cur[field].result(self.prefetch_timeout)
         except Exception:
+            self.prefetch_fallbacks += 1
             if self.faults is not None:
                 self._log_fault(cur["t"], prefetch_fallbacks=1)
             return sync_gather(cur["ids_np"])

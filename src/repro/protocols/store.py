@@ -235,15 +235,12 @@ class MemoryStore(ClientStateStore):
         self._flat = flat
         self._residual = (jnp.zeros(flat.shape, jnp.float32)
                           if residual else None)
-        try:
-            platforms = {d.platform for d in flat.devices()}
-        except Exception:
-            platforms = {"cpu"}
         #: accelerator-resident buffers take the jitted
         #: ``gather_rows_dev``/``scatter_rows_dev`` fast path: windows
         #: move device↔device with the state buffer donated through the
         #: scatter — no host round-trip at all
-        self._device_resident = platforms and "cpu" not in platforms
+        self._device_resident = all(d.platform != "cpu"
+                                    for d in flat.devices())
 
     @property
     def flat(self) -> jnp.ndarray:

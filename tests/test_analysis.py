@@ -172,7 +172,7 @@ def test_walker_nested_scan_cond_pjit():
 
     paths = {s.pretty_path for s in iter_eqns(j)}
     assert any("scan.body" in p and "cond.branch" in p for p in paths)
-    assert any("pjit.call" in p and p.endswith("dot_general") for p in paths)
+    assert any("jit.call" in p and p.endswith("dot_general") for p in paths)
 
     # loop membership survives nesting: the dot sits inside the scan body
     dots = [s for s in iter_eqns(j) if s.eqn.primitive.name == "dot_general"]
@@ -183,13 +183,12 @@ def test_census_loop_weighting_single_device():
     """census() scales collectives by trip count (1-device mesh so the
     psum traces in-process)."""
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    from repro.sharding.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     def mix(x):
-        return shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
-                         in_specs=P("data"), out_specs=P(None),
-                         check_vma=False)(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+                             in_specs=P("data"), out_specs=P(None),
+                             check_vma=False)(x)
 
     def run(x):
         def body(c, _):
@@ -231,13 +230,12 @@ def test_collective_census_mismatch_is_error():
     """A program whose wire traffic diverges from its mixing-structure
     budget — here an extra psum against an empty budget — is an ERROR."""
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    from repro.sharding.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     def leaky(x):                      # one psum the budget doesn't allow
-        return shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
-                         in_specs=P("data"), out_specs=P(None),
-                         check_vma=False)(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+                             in_specs=P("data"), out_specs=P(None),
+                             check_vma=False)(x)
 
     j = jax.make_jaxpr(leaky)(jnp.ones((1, 2)))
     prog = aprog.Program(name="fixture/leaky", jaxpr=j, engine="mesh",

@@ -80,12 +80,11 @@ def test_differ_flags_added_collective(sparse_round):
     it traces in-process) must flip the collective-census diff gate."""
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.compat import shard_map
 
     def extra_psum(args):
-        leak = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
-                         in_specs=P("data"), out_specs=P(None),
-                         check_vma=False)(jnp.ones((1, 2)))
+        leak = jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+                             in_specs=P("data"), out_specs=P(None),
+                             check_vma=False)(jnp.ones((1, 2)))
         return leak.sum()
 
     broken = _rewrap(sparse_round, extra_psum, "+psum")
@@ -246,14 +245,13 @@ def test_collective_wire_sizes_groups_and_codecs():
     the codec scales payload but not overhead."""
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.compat import shard_map
 
     def f(x):
         def local(v):
             scalar = jax.lax.psum(jnp.ones(()), "data")     # overhead
             return jax.lax.psum(v * scalar, "data")         # payload
-        return shard_map(local, mesh=mesh, in_specs=P("data"),
-                         out_specs=P(None), check_vma=False)(x)
+        return jax.shard_map(local, mesh=mesh, in_specs=P("data"),
+                             out_specs=P(None), check_vma=False)(x)
 
     j = jax.make_jaxpr(f)(jnp.ones((1, 6)))
     wire = C.collective_wire(j, bits_per_param=32.0)

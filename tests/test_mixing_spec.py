@@ -32,6 +32,7 @@ from repro.core.simulator import Simulator
 from repro.data.federated import pack_clients
 from repro.data.synthetic import syncov
 from repro.kernels import ops, ref
+from repro.kernels.fed_mix_sparse import fed_mix_matching, fed_mix_segment
 from repro.protocols import (
     MatchingSpec, SegmentSpec, apply_spec_flat, make_context,
 )
@@ -95,6 +96,36 @@ def test_sparse_flat_path_matches_dense_oracle(name, dtype):
         np.testing.assert_allclose(np.asarray(sparse_k, np.float32),
                                    np.asarray(dense, np.float32),
                                    rtol=tol, atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("d,p,block", [
+    (12, 300, {}),                                  # one ragged param tile
+    (20, 1100, dict(block_r=8, block_k=8, block_d=512)),   # padded rows,
+    (33, 1024, dict(block_r=16, block_k=32, block_d=256)),  # many tiles
+])
+def test_sparse_kernels_match_oracles_on_unaligned_shapes(d, p, block):
+    """Rows padded only past one block, the last param tile ragged: the
+    interpret-mode kernels still equal their jnp oracles."""
+    rng = np.random.default_rng(d + p)
+    xn = jnp.asarray(rng.normal(size=(d, p)).astype(np.float32))
+    xo = jnp.asarray(rng.normal(size=(d, p)).astype(np.float32))
+    cids = jnp.asarray(rng.integers(0, 3, d).astype(np.int32))
+    w_new = jnp.asarray(rng.uniform(0, 1, d).astype(np.float32))
+    w_old = jnp.asarray(rng.uniform(0, 1, d).astype(np.float32))
+    got = fed_mix_segment(cids, w_new, w_old, xn, xo, num_segments=3,
+                          interpret=True, **block)
+    want = ref.fed_mix_segment_ref(cids, w_new, w_old, xn, xo,
+                                   num_segments=3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    perms = jnp.asarray(np.stack([rng.permutation(d) for _ in range(2)])
+                        .astype(np.int32))
+    survive = jnp.asarray((rng.random(d) > 0.3).astype(np.float32))
+    block.pop("block_k", None)
+    got = fed_mix_matching(perms, survive, xn, xo, interpret=True, **block)
+    want = ref.fed_mix_matching_ref(perms, survive, xn, xo)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -335,7 +366,7 @@ def test_run_rounds_packs_global_model_once(sim_data, monkeypatch):
 def _count_data_gathers(jaxpr, data_shape):
     """# of gather eqns (recursively) whose operand is the full client
     data array — the per-round client-batch gather."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subs(eqn):
         for v in eqn.params.values():

@@ -134,6 +134,26 @@ def test_pipelined_bit_exact_under_natural_overlap(data_dev, depth, tier):
     out = se.run_rounds(key, 6)
     np.testing.assert_array_equal(out["train_loss"], out_ref["train_loss"])
     _assert_state_equal(_store_state(se), _store_state(ref))
+    assert se.prefetch_fallbacks == 0
+
+
+def test_broken_prefetch_is_counted_without_a_fault_plan(data_dev):
+    """A prefetch that raises falls back to a synchronous gather — counted
+    with no fault plan at all, so a broken pipeline cannot hide behind
+    results that stay bit-exact."""
+    class Broken(PrefetchHandle):
+        def result(self, timeout=None):
+            raise RuntimeError("prefetch worker died")
+
+    key = jax.random.PRNGKey(5)
+    ref = _engine(data_dev, 1, tier="memory")
+    out_ref = ref.run_rounds(key, 4)
+    se = _engine(data_dev, 2, tier="memory")
+    se.store.prefetch = lambda ids: Broken()
+    out = se.run_rounds(key, 4)
+    assert se.faults is None and se.prefetch_fallbacks == 4
+    np.testing.assert_array_equal(out["train_loss"], out_ref["train_loss"])
+    _assert_state_equal(_store_state(se), _store_state(ref))
 
 
 @pytest.mark.parametrize("depth", [2, 3])
