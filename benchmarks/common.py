@@ -27,12 +27,6 @@ def wallclock(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:
     return ts[len(ts) // 2] * 1e6
 
 
-def timed(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:
-    """Median wall-time (us) of fn(*args) after warmup (alias of
-    ``wallclock`` — the historical name, kept for callers)."""
-    return wallclock(fn, *args, warmup=warmup, iters=iters)
-
-
 def print_rows(rows: List[Row]) -> None:
     for name, val, derived in rows:
         print(f"{name},{val:.4f},{derived}")

@@ -6,7 +6,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import timed
+from benchmarks.common import wallclock
 from repro.kernels import ref
 from repro.kernels.fed_aggregate import fed_aggregate
 from repro.kernels.fed_mix import fed_mix
@@ -22,7 +22,7 @@ def run(quick: bool = True):
     w = jnp.ones((n,)) / n
     f_ref = jax.jit(ref.fed_aggregate_ref)
     rows.append((f"kernel/fed_aggregate_ref/{n}x{d}",
-                 timed(f_ref, x, w), "jnp oracle (XLA:CPU)"))
+                 wallclock(f_ref, x, w), "jnp oracle (XLA:CPU)"))
     out_k = fed_aggregate(x[:, :4096], w, interpret=True)
     ok = bool(jnp.allclose(out_k, ref.fed_aggregate_ref(x[:, :4096], w),
                            rtol=1e-4))
@@ -36,7 +36,7 @@ def run(quick: bool = True):
     x_old = jax.random.normal(ks[2], (n, d), jnp.float32)
     f_mix = jax.jit(ref.fed_mix_ref)
     rows.append((f"kernel/fed_mix_ref/{n}x{d}",
-                 timed(f_mix, mn, mo, x, x_old), "jnp oracle (XLA:CPU)"))
+                 wallclock(f_mix, mn, mo, x, x_old), "jnp oracle (XLA:CPU)"))
     out_m = fed_mix(mn, mo, x[:, :4096], x_old[:, :4096], interpret=True)
     ok = bool(jnp.allclose(out_m,
                            ref.fed_mix_ref(mn, mo, x[:, :4096],
@@ -59,7 +59,7 @@ def run(quick: bool = True):
         wo = jnp.asarray(rng.uniform(0, 1, D).astype(np.float32))
         xn_d = jnp.asarray(rng.normal(size=(D, n_cols)).astype(np.float32))
         xo_d = jnp.asarray(rng.normal(size=(D, n_cols)).astype(np.float32))
-        seg_us = timed(f_seg, cids, wn, wo, xn_d, xo_d)
+        seg_us = wallclock(f_seg, cids, wn, wo, xn_d, xo_d)
         rows.append((f"kernel/fed_mix_segment_ref/D{D}x{n_cols}",
                      seg_us, "jnp oracle (XLA:CPU), L=8 clusters"))
         perms = jnp.asarray(
@@ -67,12 +67,12 @@ def run(quick: bool = True):
                      ).astype(np.int32))
         sv = jnp.asarray((rng.random(D) > 0.1).astype(np.float32))
         rows.append((f"kernel/fed_mix_matching_ref/D{D}x{n_cols}",
-                     timed(f_match, perms, sv, xn_d, xo_d),
+                     wallclock(f_match, perms, sv, xn_d, xo_d),
                      "jnp oracle (XLA:CPU), 2 stages"))
         if D <= 1024:      # dense comparison column: O(D²·n) — the wall
             mn_d = jnp.asarray(rng.uniform(0, 1, (D, D)).astype(np.float32)
                                / D)
-            dense_us = timed(f_mix, mn_d, mn_d, xn_d, xo_d)
+            dense_us = wallclock(f_mix, mn_d, mn_d, xn_d, xo_d)
             rows.append((f"kernel/fed_mix_ref/D{D}x{n_cols}", dense_us,
                          "dense oracle at same (D, n)"))
             rows.append((f"kernel/fed_mix_segment_speedup_vs_dense/D{D}",
@@ -103,7 +103,7 @@ def run(quick: bool = True):
     v = jax.random.normal(key, (b, h, s, hd)) * 0.5
     f_fa = jax.jit(lambda q, k, v: ref.flash_attention_ref(q, k, v))
     rows.append((f"kernel/flash_attention_ref/b{b}h{h}s{s}",
-                 timed(f_fa, q, k, v), "jnp oracle"))
+                 wallclock(f_fa, q, k, v), "jnp oracle"))
 
     bs, ss, hh, p, nn = 2, (512 if quick else 2048), 4, 64, 64
     x2 = jax.random.normal(key, (bs, ss, hh, p)) * 0.5
@@ -114,7 +114,7 @@ def run(quick: bool = True):
     from repro.models.ssm import ssd_chunked
     f_ssd = jax.jit(lambda *a: ssd_chunked(*a, 128))
     rows.append((f"kernel/ssd_chunked/b{bs}s{ss}",
-                 timed(f_ssd, x2, dt, A, B, C), "chunked jnp (kernel oracle)"))
+                 wallclock(f_ssd, x2, dt, A, B, C), "chunked jnp (kernel oracle)"))
     return rows
 
 

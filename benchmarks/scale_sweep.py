@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import timed
+from benchmarks.common import wallclock
 from repro import protocols
 from repro.config import FLConfig
 from repro.protocols import apply_spec_flat, make_context
@@ -77,9 +77,9 @@ def sweep_one(name: str, D: int, n: int, *, iters: int = 3):
     xo = jnp.asarray(rng.normal(size=(D, n)).astype(np.float32))
     key = jax.random.PRNGKey(0)
     with_dense = D <= DENSE_MAX_D.get(name, FULL_DS[-1])
-    dense_us = (timed(jax.jit(dense_fn), xn, xo, key, iters=iters)
+    dense_us = (wallclock(jax.jit(dense_fn), xn, xo, key, iters=iters)
                 if with_dense else 0.0)
-    sparse_us = timed(jax.jit(sparse_fn), xn, xo, key, iters=iters)
+    sparse_us = wallclock(jax.jit(sparse_fn), xn, xo, key, iters=iters)
     dense_mib = _temp_mib(dense_fn, xn, xo, key) if with_dense else 0.0
     return dense_us, sparse_us, dense_mib, _temp_mib(sparse_fn, xn, xo, key)
 
@@ -121,7 +121,7 @@ def sweep_sampled(name: str, D: int, K: int, n: int, *, iters: int = 3):
     key = jax.random.PRNGKey(0)
     # D reaches the compiled program only as VALUES of the [K] id vector —
     # the jit signature (and hence the compiled round cost) is D-free
-    window_us = timed(jax.jit(window_fn), xn, xo, jnp.asarray(ids_np), key,
+    window_us = wallclock(jax.jit(window_fn), xn, xo, jnp.asarray(ids_np), key,
                       iters=iters)
 
     store = make_store(jnp.zeros((n,), jnp.float32), D)

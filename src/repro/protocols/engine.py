@@ -105,6 +105,7 @@ def make_local_trainer(net: PaperNetConfig, fl: FLConfig):
     client; callers vmap it over participants."""
     bs = fl.batch_size
 
+    @jax.named_scope("local_train")
     def local_train(params, cx, cy, cmask, key):
         n_max = cy.shape[0]
         steps = max(1, -(-n_max // bs))               # ceil
@@ -504,6 +505,10 @@ class DenseEngine:
         ``run_rounds`` call == one training run on this engine; drive
         ``round_fn`` directly to thread residuals across calls)."""
         T, eval_every = int(T), max(1, int(eval_every))
+        with jax.profiler.TraceAnnotation("fl.run_rounds", rounds=T):
+            return self._run_rounds(params, key, T, eval_every)
+
+    def _run_rounds(self, params, key, T: int, eval_every: int):
         flat0, spec = self._pack_params(params)      # packed ONCE per call
         # the compiled run closes over the TreeSpec, so the cache must key
         # on the params *structure* too — two layouts can share a packed
@@ -899,24 +904,31 @@ class SampledEngine:
         if self.store is None:
             raise ValueError("SampledEngine.round: call init_store(params) "
                              "first — the engine has no enrolled state")
-        if self.faults is not None:
-            return self._round_faulted(key, round_index)
+        with jax.profiler.TraceAnnotation("fl.round", round=int(round_index)):
+            if self.faults is not None:
+                return self._round_faulted(key, round_index)
+            return self._round_serial(key, round_index)
+
+    def _round_serial(self, key, round_index: int):
         k_sel, k_tr, k_str, k_mix = jax.random.split(key, 4)
-        active_ids = self.select_fn(k_sel)
-        ids_np = np.asarray(active_ids)
+        with jax.profiler.TraceAnnotation("fl.select"):
+            active_ids = self.select_fn(k_sel)
+            ids_np = np.asarray(active_ids)
         flat_win = self.store.gather(ids_np)
         if self._codec_stateful:
             res = self.store.gather_residual(ids_np)
-            flat_mixed, loss, res = self.window_fn(
-                flat_win, active_ids, k_tr, k_str, k_mix,
-                jnp.asarray(round_index, jnp.int32), res)
+            with jax.profiler.TraceAnnotation("fl.window"):
+                flat_mixed, loss, res = self.window_fn(
+                    flat_win, active_ids, k_tr, k_str, k_mix,
+                    jnp.asarray(round_index, jnp.int32), res)
             # the store converts ONCE at its seam (np for the cold tier,
             # zero-copy for device tiers) — no np.asarray here
             self.store.scatter_residual(ids_np, res)
         else:
-            flat_mixed, loss = self.window_fn(
-                flat_win, active_ids, k_tr, k_str, k_mix,
-                jnp.asarray(round_index, jnp.int32))
+            with jax.profiler.TraceAnnotation("fl.window"):
+                flat_mixed, loss = self.window_fn(
+                    flat_win, active_ids, k_tr, k_str, k_mix,
+                    jnp.asarray(round_index, jnp.int32))
         self.store.scatter(ids_np, flat_mixed)
         self.store.touch(ids_np, round_index)
         return loss
@@ -932,7 +944,9 @@ class SampledEngine:
         inj.begin_round(round_index)
         spec = self.faults.for_round(round_index)
         k_sel, k_tr, k_str, k_mix = jax.random.split(key, 4)
-        ids_np = self._splice_retries(np.asarray(self.select_fn(k_sel)))
+        with jax.profiler.TraceAnnotation("fl.select"):
+            ids_np = np.asarray(self.select_fn(k_sel))
+        ids_np = self._splice_retries(ids_np)
         active_ids = jnp.asarray(ids_np)
         drop, flag, mode = self._fault_vectors(spec, ids_np)
         r0 = self.store.read_retry_count
@@ -940,16 +954,18 @@ class SampledEngine:
         t_idx = jnp.asarray(round_index, jnp.int32)
         if self._codec_stateful:
             res = self.store.gather_residual(ids_np)
-            flat_out, loss, bad, res = self.window_fault_fn(
-                flat_win, active_ids, k_tr, k_str, k_mix,
-                jnp.asarray(drop), jnp.asarray(flag), jnp.asarray(mode),
-                t_idx, res)
+            with jax.profiler.TraceAnnotation("fl.window"):
+                flat_out, loss, bad, res = self.window_fault_fn(
+                    flat_win, active_ids, k_tr, k_str, k_mix,
+                    jnp.asarray(drop), jnp.asarray(flag), jnp.asarray(mode),
+                    t_idx, res)
             self.store.scatter_residual(ids_np, res)
         else:
-            flat_out, loss, bad = self.window_fault_fn(
-                flat_win, active_ids, k_tr, k_str, k_mix,
-                jnp.asarray(drop), jnp.asarray(flag), jnp.asarray(mode),
-                t_idx)
+            with jax.profiler.TraceAnnotation("fl.window"):
+                flat_out, loss, bad = self.window_fault_fn(
+                    flat_win, active_ids, k_tr, k_str, k_mix,
+                    jnp.asarray(drop), jnp.asarray(flag), jnp.asarray(mode),
+                    t_idx)
         bad_np = np.asarray(bad).astype(bool)
         self.store.scatter(ids_np, flat_out)
         touch_ids = self._requeue_rejected(ids_np, bad_np, drop, round_index)
@@ -971,7 +987,8 @@ class SampledEngine:
         has long finished."""
         k_sel, k_tr, k_str, k_mix = jax.random.split(
             jax.random.fold_in(key, t), 4)
-        active_ids = self.select_fn(k_sel)
+        with jax.profiler.TraceAnnotation("fl.select"):
+            active_ids = self.select_fn(k_sel)
         if self.faults is not None:
             # fault mode: the injector is armed BEFORE the prefetch goes
             # out (round t's store reads are the ones its spec targets —
@@ -1073,6 +1090,24 @@ class SampledEngine:
         touch = p.get("touch_ids")
         self.store.touch(p["ids_np"] if touch is None else touch, p["t"])
 
+    def _dispatch_window(self, cur, flat_win, res, t: int):
+        """Stage B: dispatch round t's compiled window (async). Returns
+        (out_flat, loss, bad, out_res); ``bad`` is None without a fault
+        plan and ``out_res`` None without a stateful codec."""
+        k_tr, k_str, k_mix = cur["keys"]
+        args = (flat_win, cur["active_ids"], k_tr, k_str, k_mix)
+        t_idx = jnp.asarray(t, jnp.int32)
+        extra = (res,) if self._codec_stateful else ()
+        bad = None
+        with jax.profiler.TraceAnnotation("fl.window"):
+            if self.faults is not None:
+                fxs = tuple(jnp.asarray(v) for v in cur["fault"])
+                out = self.window_fault_fn(*args, *fxs, t_idx, *extra)
+                bad = out[2]
+            else:
+                out = self.window_fn(*args, t_idx, *extra)
+        return out[0], out[1], bad, (out[-1] if extra else None)
+
     def _run_rounds_pipelined(self, key, T: int, depth: int):
         """T rounds with up to ``depth`` windows in flight. Per loop
         iteration: acquire round t's prefetched window (patching id
@@ -1085,60 +1120,39 @@ class SampledEngine:
         pending, shadow, losses = [], [], [None] * T
         nxt = self._issue_round(key, 0) if T > 0 else None
         for t in range(T):
-            cur = nxt
-            flat_win, res = self._acquire_window(cur, shadow, pending)
-            # every prefetch issued from here on sees the shadow rounds'
-            # scatters (they completed before this point) — drop them
-            shadow.clear()
-            k_tr, k_str, k_mix = cur["keys"]
-            bad = None
-            if self.faults is not None:
-                drop, flag, mode = cur["fault"]
-                fxs = (jnp.asarray(drop), jnp.asarray(flag),
-                       jnp.asarray(mode))
-                if self._codec_stateful:
-                    out_flat, loss, bad, out_res = self.window_fault_fn(
-                        flat_win, cur["active_ids"], k_tr, k_str, k_mix,
-                        *fxs, jnp.asarray(t, jnp.int32), res)
-                else:
-                    out_res = None
-                    out_flat, loss, bad = self.window_fault_fn(
-                        flat_win, cur["active_ids"], k_tr, k_str, k_mix,
-                        *fxs, jnp.asarray(t, jnp.int32))
-            elif self._codec_stateful:
-                out_flat, loss, out_res = self.window_fn(
-                    flat_win, cur["active_ids"], k_tr, k_str, k_mix,
-                    jnp.asarray(t, jnp.int32), res)
-            else:
-                out_res = None
-                out_flat, loss = self.window_fn(
-                    flat_win, cur["active_ids"], k_tr, k_str, k_mix,
-                    jnp.asarray(t, jnp.int32))
-            if host_retire:
-                # start the device->host copy NOW so stage C's np
-                # conversion doesn't block on the transfer later
-                for buf in (out_flat, out_res):
-                    if buf is not None and hasattr(buf,
-                                                   "copy_to_host_async"):
-                        buf.copy_to_host_async()
-            cur.update(out_flat=out_flat, out_res=out_res)
-            losses[t] = loss
-            pending.append(cur)
-            if self.faults is not None:
-                # host-sync the guard verdict BEFORE issuing round t+1 so
-                # the requeue splice sees this round's rejections at every
-                # depth — fault mode trades that slice of overlap for
-                # depth-invariant cold-retry semantics
-                bad_np = np.asarray(bad).astype(bool)
-                cur["touch_ids"] = self._requeue_rejected(
-                    cur["ids_np"], bad_np, cur["fault"][0], t)
-                self._log_fault(
-                    t, retries=self.store.read_retry_count - cur["r0"])
-            nxt = self._issue_round(key, t + 1) if t + 1 < T else None
-            while len(pending) > depth - 1:
-                p = pending.pop(0)
-                self._retire_round(p)
-                shadow.append(p)
+            with jax.profiler.TraceAnnotation("fl.round", round=t):
+                cur = nxt
+                flat_win, res = self._acquire_window(cur, shadow, pending)
+                # every prefetch issued from here on sees the shadow rounds'
+                # scatters (they completed before this point) — drop them
+                shadow.clear()
+                out_flat, loss, bad, out_res = self._dispatch_window(
+                    cur, flat_win, res, t)
+                if host_retire:
+                    # start the device->host copy NOW so stage C's np
+                    # conversion doesn't block on the transfer later
+                    for buf in (out_flat, out_res):
+                        if buf is not None and hasattr(buf,
+                                                       "copy_to_host_async"):
+                            buf.copy_to_host_async()
+                cur.update(out_flat=out_flat, out_res=out_res)
+                losses[t] = loss
+                pending.append(cur)
+                if self.faults is not None:
+                    # host-sync the guard verdict BEFORE issuing round t+1
+                    # so the requeue splice sees this round's rejections at
+                    # every depth — fault mode trades that slice of overlap
+                    # for depth-invariant cold-retry semantics
+                    bad_np = np.asarray(bad).astype(bool)
+                    cur["touch_ids"] = self._requeue_rejected(
+                        cur["ids_np"], bad_np, cur["fault"][0], t)
+                    self._log_fault(
+                        t, retries=self.store.read_retry_count - cur["r0"])
+                nxt = self._issue_round(key, t + 1) if t + 1 < T else None
+                while len(pending) > depth - 1:
+                    p = pending.pop(0)
+                    self._retire_round(p)
+                    shadow.append(p)
         for p in pending:
             self._retire_round(p)
         return losses
@@ -1153,12 +1167,16 @@ class SampledEngine:
         plan the dict grows the four per-round counters ``dropped``,
         ``rejected_rows``, ``retries`` and ``prefetch_fallbacks`` ([T]
         int64)."""
+        T = int(T)
+        with jax.profiler.TraceAnnotation("fl.run_rounds", rounds=T):
+            return self._run_rounds(key, T, pipeline_depth)
+
+    def _run_rounds(self, key, T: int, pipeline_depth: Optional[int]):
         if self.store is None:
             raise ValueError("SampledEngine.run_rounds: call "
                              "init_store(params) first")
         depth = self._check_depth(self.pipeline_depth if pipeline_depth
                                   is None else pipeline_depth)
-        T = int(T)
         if self.faults is not None:
             # one run_rounds call == one chaos run: counters and the cold-
             # retry queue start clean
@@ -1250,6 +1268,7 @@ class MeshEngine:
                         if counts is None
                         else jnp.asarray(counts, jnp.float32))
 
+        @jax.named_scope("local_train")
         def local_train(params, batches):
             def step(p, b):
                 (loss, _), grads = jax.value_and_grad(
@@ -1421,6 +1440,7 @@ class MeshEngine:
         got = jax.tree.leaves(batches)[0].shape[0]
         if got != T:
             raise ValueError(f"batches carry {got} rounds, expected T={T}")
-        if self._codec_stateful:
-            return self._run_jit(f_params, key, batches, codec_state)
-        return self._run_jit(f_params, key, batches)
+        with jax.profiler.TraceAnnotation("fl.run_rounds", rounds=T):
+            if self._codec_stateful:
+                return self._run_jit(f_params, key, batches, codec_state)
+            return self._run_jit(f_params, key, batches)

@@ -251,22 +251,26 @@ class MemoryStore(ClientStateStore):
         return self._flat
 
     def gather(self, ids) -> jnp.ndarray:
-        ids = jnp.asarray(self._check_ids(ids))
-        if self._device_resident:
-            return kernel_ops.gather_rows_dev(self._flat, ids)
-        return kernel_ops.gather_rows(self._flat, ids)
+        ids = self._check_ids(ids)
+        with jax.profiler.TraceAnnotation("fl.store.gather", rows=int(ids.size)):
+            ids = jnp.asarray(ids)
+            if self._device_resident:
+                return kernel_ops.gather_rows_dev(self._flat, ids)
+            return kernel_ops.gather_rows(self._flat, ids)
 
     def scatter(self, ids, rows) -> None:
         # ``rows`` arrives as whatever the engine produced (usually the
         # still-device-resident window output); jnp.asarray is zero-copy
         # for device arrays — the ONE conversion happens here, at the seam
-        ids = jnp.asarray(self._check_ids(ids))
-        if self._device_resident:
-            self._flat = kernel_ops.scatter_rows_dev(
-                self._flat, ids, jnp.asarray(rows))
-        else:
-            self._flat = kernel_ops.scatter_rows(self._flat, ids,
-                                                 jnp.asarray(rows))
+        ids = self._check_ids(ids)
+        with jax.profiler.TraceAnnotation("fl.store.scatter", rows=int(ids.size)):
+            ids = jnp.asarray(ids)
+            if self._device_resident:
+                self._flat = kernel_ops.scatter_rows_dev(
+                    self._flat, ids, jnp.asarray(rows))
+            else:
+                self._flat = kernel_ops.scatter_rows(self._flat, ids,
+                                                     jnp.asarray(rows))
 
     def gather_residual(self, ids) -> jnp.ndarray:
         if self._residual is None:
@@ -451,26 +455,37 @@ class CheckpointStore(ClientStateStore):
 
     def gather(self, ids) -> jnp.ndarray:
         ids = self._check_ids(ids)
-        cold = np.array([i for i, c in enumerate(ids)
-                         if int(c) not in self._overlay], np.int64)
-        out = np.empty((ids.size, self.width), self.dtype)
-        if cold.size:
-            out[cold] = self._base_rows(ids[cold])
-        for i, c in enumerate(ids):
-            row = self._overlay.get(int(c))
-            if row is not None:
-                out[i] = row
-        return jnp.asarray(out)
+        with jax.profiler.TraceAnnotation("fl.store.gather",
+                                          rows=int(ids.size)) as span:
+            cold = np.array([i for i, c in enumerate(ids)
+                             if int(c) not in self._overlay], np.int64)
+            span.set_metadata(cold_rows=int(cold.size))
+            out = np.empty((ids.size, self.width), self.dtype)
+            if cold.size:
+                out[cold] = self._base_rows(ids[cold])
+            for i, c in enumerate(ids):
+                row = self._overlay.get(int(c))
+                if row is not None:
+                    out[i] = row
+            with jax.profiler.TraceAnnotation("fl.store.to_device",
+                                              bytes=int(out.nbytes)):
+                return jnp.asarray(out)
 
     def scatter(self, ids, rows) -> None:
         ids = self._check_ids(ids)
-        rows = np.asarray(rows, self.dtype)
-        if rows.shape != (ids.size, self.width):
-            raise ValueError(
-                f"CheckpointStore.scatter: window shape {rows.shape} does "
-                f"not match ({ids.size}, {self.width})")
-        for i, c in enumerate(ids):
-            self._overlay[int(c)] = rows[i].copy()
+        with jax.profiler.TraceAnnotation("fl.store.scatter",
+                                          rows=int(ids.size)):
+            # the read waits for the program that computes ``rows``: the
+            # device is busy then, so idle time inside is the transfer
+            with jax.profiler.TraceAnnotation("fl.store.to_host",
+                                              bytes=int(rows.nbytes)):
+                rows = np.asarray(rows, self.dtype)
+            if rows.shape != (ids.size, self.width):
+                raise ValueError(
+                    f"CheckpointStore.scatter: window shape {rows.shape} "
+                    f"does not match ({ids.size}, {self.width})")
+            for i, c in enumerate(ids):
+                self._overlay[int(c)] = rows[i].copy()
 
     def gather_residual(self, ids) -> jnp.ndarray:
         ids = self._check_ids(ids)
