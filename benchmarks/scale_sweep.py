@@ -125,7 +125,7 @@ def sweep_sampled(name: str, D: int, K: int, n: int, *, iters: int = 3):
                       iters=iters)
 
     store = make_store(jnp.zeros((n,), jnp.float32), D)
-    store.scatter(ids_np, np.asarray(xo))       # warm: rows become overlay
+    store.scatter(ids_np, np.asarray(xo))       # warm: rows take arena slots
 
     def store_roundtrip():
         win = store.gather(ids_np)
@@ -139,7 +139,7 @@ def sweep_sampled(name: str, D: int, K: int, n: int, *, iters: int = 3):
 # the mixing op) driven through run_rounds at growing pipeline_depth —
 # depth 1 is the serial baseline, depths 2-3 overlap store prefetch and
 # retire/scatter with the compiled window. Tiers: the resident MemoryStore
-# (device buffer, D=10^4) and the overlay CheckpointStore (host-owned,
+# (device buffer, D=10^4) and the arena CheckpointStore (host-owned,
 # D=10^6 — the regime where store I/O sits on the serial critical path).
 PIPELINE_DEPTHS = (1, 2, 3)
 PIPELINE_TIERS = (("resident", "memory", 10 ** 4),

@@ -176,7 +176,7 @@ def sampled_programs(protocol: str, *, codec: str = "none",
                            codec=None if codec == "none" else codec,
                            mix_path=mix_path)
     # the store is host-side and never traced; init only supplies the
-    # packed TreeSpec (auto tier lands on the overlay store at this D)
+    # packed TreeSpec (auto tier lands on the checkpoint store at this D)
     engine.init_store(engine.init_params(0))
     width = engine.store.width
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))

@@ -89,7 +89,10 @@ def _restore_dtype(a: np.ndarray, dt: Optional[str]) -> np.ndarray:
     return a
 
 
-def load_leaves(path: str, indices: Sequence[int]) -> Tuple[List[np.ndarray], Dict]:
+def load_leaves(path: str, indices: Sequence[int], *,
+                out: Optional[Sequence[np.ndarray]] = None,
+                at: Optional[np.ndarray] = None
+                ) -> Tuple[List[np.ndarray], Dict]:
     """Partial-row reads: fetch only the given leading-axis rows of every
     leaf in one checkpoint file, without materializing the full arrays.
 
@@ -102,11 +105,23 @@ def load_leaves(path: str, indices: Sequence[int]) -> Tuple[List[np.ndarray], Di
     Returns ``(leaves, meta)`` where ``leaves[i]`` has shape
     ``[len(indices), *trailing_i]`` with the checkpointed dtype restored
     (bf16 leaves come back as bf16, not their uint16 storage view).
+
+    ``out`` fills caller-owned arrays instead of fresh ones: ``out[i]``
+    (for the first ``len(out)`` leaves) receives requested row ``j`` at
+    ``out[i][at[j]]`` (``at`` defaults to ``0..len(indices)-1``) and is
+    returned as ``leaves[i]``; its trailing shape and dtype must be the
+    leaf's, and it must be C-contiguous.
     """
     idx = np.asarray(indices, dtype=np.int64)
     if idx.ndim != 1:
         raise ValueError(f"load_leaves: indices must be 1-D, got shape "
                          f"{idx.shape}")
+    fresh = np.arange(idx.size)
+    at = fresh if at is None else np.asarray(at, dtype=np.int64)
+    if at.shape != idx.shape:
+        raise ValueError(f"load_leaves: at has shape {at.shape}, indices "
+                         f"{idx.shape}")
+    out = list(out or ())
     try:
         zf_ctx = zipfile.ZipFile(path)
     except zipfile.BadZipFile as e:
@@ -131,7 +146,12 @@ def load_leaves(path: str, indices: Sequence[int]) -> Tuple[List[np.ndarray], Di
                 # a full read of this leaf only
                 with zf.open(member) as fh:
                     full = np.lib.format.read_array(fh, allow_pickle=False)
-                leaves.append(_restore_dtype(full[idx].copy(), dt))
+                rows = _restore_dtype(full[idx], dt)
+                if i < len(out):
+                    _check_out(out[i], rows.shape[1:], rows.dtype, i)
+                    out[i][at] = rows
+                    rows = out[i]
+                leaves.append(rows)
                 continue
             with zf.open(member) as fh:
                 version = np.lib.format.read_magic(fh)
@@ -159,21 +179,36 @@ def load_leaves(path: str, indices: Sequence[int]) -> Tuple[List[np.ndarray], Di
                     raise IndexError(
                         f"load_leaves: indices {bad[:4].tolist()} out of "
                         f"range for leaf {i} with {shape[0]} rows")
-                out = np.empty((idx.size,) + row_shape, dtype)
-                flat = out.reshape(idx.size, -1)
-                for j, r in enumerate(idx):
-                    fh.seek(data_start + int(r) * row_bytes)
+                if i < len(out):
+                    dst, pos = out[i], at
+                    _check_out(dst, row_shape,
+                               _restore_dtype(np.empty(0, dtype), dt).dtype, i)
+                else:
+                    dst, pos = _restore_dtype(
+                        np.empty((idx.size,) + row_shape, dtype), dt), fresh
+                flat = dst.reshape(dst.shape[0], -1).view(dtype)
+                for j, r in zip(pos.tolist(), idx.tolist()):
+                    fh.seek(data_start + r * row_bytes)
                     buf = fh.read(row_bytes)
                     if len(buf) != row_bytes:
                         raise CheckpointCorruptionError(
                             f"checkpoint {path!r} is truncated: leaf {i} "
-                            f"row {int(r)} (requested rows "
+                            f"row {r} (requested rows "
                             f"{int(idx.min())}..{int(idx.max())} of "
                             f"{shape[0]}) yielded {len(buf)} of "
                             f"{row_bytes} bytes")
                     flat[j] = np.frombuffer(buf, dtype)
-                leaves.append(_restore_dtype(out, dt))
+                leaves.append(dst)
     return leaves, meta
+
+
+def _check_out(dst: np.ndarray, row_shape, dtype, leaf: int) -> None:
+    if (dst.shape[1:] != tuple(row_shape) or dst.dtype != dtype
+            or not dst.flags.c_contiguous):
+        raise ValueError(
+            f"load_leaves: out[{leaf}] must be C-contiguous rows of "
+            f"{tuple(row_shape)} {np.dtype(dtype)}, got {dst.shape[1:]} "
+            f"{dst.dtype}")
 
 
 def load_checkpoint(ckpt_dir: str, tree_like: Any,

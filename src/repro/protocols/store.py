@@ -24,8 +24,9 @@ Tiers (``make_store`` picks by footprint):
                         ``base_row`` (or a row of an on-disk npz checkpoint
                         read via ``checkpoint.io.load_leaves`` partial-row
                         reads), and only rows a round actually touched are
-                        held in a host overlay dict. Memory scales with
-                        rounds x K, not D.
+                        held, in a host slab arena (``_RowArena``). Memory
+                        scales with the touched rows, not D; gathers stage
+                        through one reused host window.
 
 Both tiers carry per-client error-feedback/codec residuals (same
 gather/scatter window discipline, f32, zeros for untouched clients) and
@@ -41,7 +42,7 @@ import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
 from concurrent.futures import TimeoutError as _FutureTimeout
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Tuple
 
 
 import jax
@@ -56,6 +57,9 @@ from repro.kernels import ops as kernel_ops
 #: footprint (bytes of [D, sum(sizes)] at f32) above which ``make_store``
 #: refuses to materialize a resident buffer and drops to the cold tier
 MEMORY_TIER_MAX_BYTES = 2 ** 31
+
+#: rows per slab of a ``_RowArena``: the unit its host memory grows by
+SLAB_ROWS = 64
 
 #: every live prefetch pool, so interpreter exit can never hang on a
 #: forgotten non-daemon fetch thread (the lifecycle bug this replaces:
@@ -292,13 +296,78 @@ class MemoryStore(ClientStateStore):
         return np.asarray(jnp.mean(self._flat.astype(jnp.float32), axis=0))
 
 
+class _RowArena:
+    """The written rows of a [D, width] host table, in slabs of
+    ``SLAB_ROWS`` rows allocated as clients are first written and reused
+    ever after. ``slot[c]`` is client c's row in the arena, -1 while c
+    was never written: host memory grows with written rows (plus the [D]
+    int64 index), never with D x width.
+
+    One thread writes (the store's scatters); gathers may read from the
+    fetch thread meanwhile. A new slot is published in ``slot`` only after
+    its row is written, so a reader sees either -1 or a whole first row;
+    an overwrite of a published row may be read torn (numpy copies without
+    the GIL) — see ``CheckpointStore.prefetch``."""
+
+    def __init__(self, num_rows: int, width: int, dtype):
+        self.slot = np.full((num_rows,), -1, np.int64)
+        self.width = int(width)
+        self.dtype = np.dtype(dtype)
+        self._slabs: List[np.ndarray] = []
+        self._count = 0
+
+    def __len__(self) -> int:
+        return self._count
+
+    def row(self, s: int) -> np.ndarray:
+        return self._slabs[s // SLAB_ROWS][s % SLAB_ROWS]
+
+    def read_into(self, out: np.ndarray, slots: np.ndarray) -> None:
+        """``out[i] = row(slots[i])`` wherever ``slots[i] >= 0``; rows of
+        never-written clients are left as they are."""
+        for i in np.flatnonzero(slots >= 0).tolist():
+            out[i] = self.row(int(slots[i]))
+
+    def write(self, ids: np.ndarray, rows: np.ndarray) -> int:
+        """Write ``rows[i]`` as client ``ids[i]``'s row, in order (a
+        repeated id keeps its last row). Returns how many ids took a new
+        slot."""
+        new = 0
+        for c, row in zip(ids.tolist(), rows):
+            s = int(self.slot[c])
+            if s >= 0:
+                self.row(s)[...] = row
+                continue
+            s = self._count
+            if s // SLAB_ROWS == len(self._slabs):
+                self._slabs.append(
+                    np.empty((SLAB_ROWS, self.width), self.dtype))
+            self.row(s)[...] = row
+            self._count += 1
+            self.slot[c] = s                 # published after the write
+            new += 1
+        return new
+
+    def written(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(ids, slots) of every written client, in first-write order."""
+        ids = np.flatnonzero(self.slot >= 0)
+        slots = self.slot[ids]
+        order = np.argsort(slots)
+        return ids[order], slots[order]
+
+
 class CheckpointStore(ClientStateStore):
     """Cold tier: untouched clients hold a shared base row implicitly;
-    touched rows live in a host overlay dict. ``base`` is either a [width]
+    touched rows live in a host slab arena. ``base`` is either a [width]
     row (fresh enrollment: every client starts at the global init) or a
     path to an npz checkpoint holding one [D, width] leaf, whose rows are
     fetched on demand with ``checkpoint.io.load_leaves`` partial-row reads
-    — a K-row gather out of a D=10^6-row file reads K rows, not D."""
+    — a K-row gather out of a D=10^6-row file reads K rows, not D.
+
+    A gather stacks its window in a store-owned [K, width] host staging
+    buffer, allocated at the first gather of K rows and reused by every
+    later one (its pages stay mapped and warm), and ships a copy of it to
+    the device before the buffer can be written again."""
 
     def __init__(self, base, num_enrolled: int, *, width: Optional[int] = None,
                  dtype=jnp.float32, read_retries: int = 0,
@@ -321,9 +390,15 @@ class CheckpointStore(ClientStateStore):
             width, dtype = row.shape[0], row.dtype
         super().__init__(num_enrolled, width)
         self.dtype = np.dtype(dtype)
-        #: touched rows only: {client id -> [width] np row}
-        self._overlay: Dict[int, np.ndarray] = {}
-        self._residual_overlay: Dict[int, np.ndarray] = {}
+        #: touched rows only, state and codec residual
+        self._rows = _RowArena(self.num_enrolled, self.width, self.dtype)
+        self._residual_rows = _RowArena(self.num_enrolled, self.width,
+                                        np.float32)
+        #: {dtype -> [rows, width] staging buffer} of the state and the
+        #: residual gathers; the lock keeps the fetch thread and the
+        #: caller's thread (a synchronous fallback, a readout) apart
+        self._staging: Dict[np.dtype, np.ndarray] = {}
+        self._staging_lock = threading.Lock()
         #: lazily-started background fetch thread for prefetch(): the
         #: ``load_leaves`` partial-row file reads block the host, so they
         #: run off-thread to overlap the compiled window. One worker —
@@ -400,12 +475,15 @@ class CheckpointStore(ClientStateStore):
         return fn(ids)
 
     def prefetch(self, ids) -> PrefetchHandle:
-        """Background-thread gather: safe against concurrent ``scatter``
-        because ``gather`` only does per-id ``dict.get``/membership reads
-        (never iterates the overlay) and ``scatter`` replaces whole rows
-        atomically under the GIL. A racing read of a conflicting id may
-        return the pre-scatter row — the pipelined engine detects id
-        overlaps on the host and patches those rows before use.
+        """Background-thread gather, racing the caller's ``scatter``s.
+        The gather reads each id's slot once; a first-written client's
+        slot appears only after its row is whole, so the read gets the
+        base row or that whole row. An overwrite of an existing row copies
+        without the GIL, so a racing gather may read that row torn — but
+        only rows of a round still in flight when the prefetch was issued
+        can be written meanwhile, and the pipelined engine's
+        ``_acquire_window`` rewrites every such row from its in-flight
+        output (``shadow``/``pending``) before use.
 
         ``ids`` may be a still-computing DEVICE array (e.g. the jitted
         selection's output): the host materialization then happens on the
@@ -422,18 +500,55 @@ class CheckpointStore(ClientStateStore):
 
     @property
     def num_touched(self) -> int:
-        return len(self._overlay)
+        return len(self._rows)
 
-    def _base_rows(self, ids: np.ndarray) -> np.ndarray:
-        """One base read, retried: transient ``OSError``s (a flaky disk, an
-        injected fault) are retried up to ``read_retries`` times with
-        exponential backoff; ``CheckpointCorruptionError`` is permanent
-        (bad bytes — a retry re-reads the same bytes) and raises through
-        immediately."""
+    def touched_rows(self, residual: bool = False
+                     ) -> Tuple[np.ndarray, np.ndarray]:
+        """(ids, rows): every client the state tier (``residual``: the
+        codec residual tier) holds a written row for, in first-write
+        order, and a copy of those [n, width] rows."""
+        arena = self._residual_rows if residual else self._rows
+        ids, slots = arena.written()
+        rows = np.empty((ids.size, self.width), arena.dtype)
+        arena.read_into(rows, slots)
+        return ids, rows
+
+    def _stage(self, dtype, n: int) -> np.ndarray:
+        """The [n, width] prefix of the staging buffer for ``dtype``,
+        grown (never shrunk) to the largest window asked for. Call under
+        ``_staging_lock``."""
+        buf = self._staging.get(np.dtype(dtype))
+        if buf is None or buf.shape[0] < n:
+            buf = np.empty((n, self.width), dtype)
+            self._staging[buf.dtype] = buf
+        return buf[:n]
+
+    @staticmethod
+    def _ship(win: np.ndarray) -> jnp.ndarray:
+        """A device copy of a staged window that shares no memory with it
+        (the window program donates its input), complete on return so
+        the caller may reuse the buffer. The CPU backend wraps a suitably
+        aligned host array in place whatever ``may_alias`` says; such a
+        window is copied once more, device to device, where a jax.Array
+        input does honour it."""
+        out = jax.device_put(win, may_alias=False)
+        if (next(iter(out.devices())).platform == "cpu"
+                and out.unsafe_buffer_pointer() == win.ctypes.data):
+            out = jax.device_put(out, may_alias=False)
+        out.block_until_ready()
+        return out
+
+    def _base_rows(self, ids: np.ndarray, out: np.ndarray,
+                   at: np.ndarray) -> None:
+        """One base read into ``out[at]``, retried: transient ``OSError``s
+        (a flaky disk, an injected fault) are retried up to
+        ``read_retries`` times with exponential backoff;
+        ``CheckpointCorruptionError`` is permanent (bad bytes — a retry
+        re-reads the same bytes) and raises through immediately."""
         attempt = 0
         while True:
             try:
-                return self._base_rows_once(ids)
+                return self._base_rows_once(ids, out, at)
             except CheckpointCorruptionError:
                 raise
             except OSError:
@@ -444,37 +559,35 @@ class CheckpointStore(ClientStateStore):
                 attempt += 1
                 self.read_retry_count += 1
 
-    def _base_rows_once(self, ids: np.ndarray) -> np.ndarray:
+    def _base_rows_once(self, ids: np.ndarray, out: np.ndarray,
+                        at: np.ndarray) -> None:
         if self.fault_injector is not None:
             self.fault_injector.on_read()
         if self._base_row is not None:
-            return np.broadcast_to(self._base_row,
-                                   (ids.size, self.width)).copy()
-        leaves, _ = load_leaves(self._base_path, ids)
-        return np.asarray(leaves[0])
+            out[at] = self._base_row
+        else:
+            load_leaves(self._base_path, ids, out=[out], at=at)
 
     def gather(self, ids) -> jnp.ndarray:
         ids = self._check_ids(ids)
         with jax.profiler.TraceAnnotation("fl.store.gather",
                                           rows=int(ids.size)) as span:
-            cold = np.array([i for i, c in enumerate(ids)
-                             if int(c) not in self._overlay], np.int64)
+            slots = self._rows.slot[ids]
+            cold = np.flatnonzero(slots < 0)
             span.set_metadata(cold_rows=int(cold.size))
-            out = np.empty((ids.size, self.width), self.dtype)
-            if cold.size:
-                out[cold] = self._base_rows(ids[cold])
-            for i, c in enumerate(ids):
-                row = self._overlay.get(int(c))
-                if row is not None:
-                    out[i] = row
-            with jax.profiler.TraceAnnotation("fl.store.to_device",
-                                              bytes=int(out.nbytes)):
-                return jnp.asarray(out)
+            with self._staging_lock:
+                out = self._stage(self.dtype, ids.size)
+                if cold.size:
+                    self._base_rows(ids[cold], out, cold)
+                self._rows.read_into(out, slots)
+                with jax.profiler.TraceAnnotation("fl.store.to_device",
+                                                  bytes=int(out.nbytes)):
+                    return self._ship(out)
 
     def scatter(self, ids, rows) -> None:
         ids = self._check_ids(ids)
         with jax.profiler.TraceAnnotation("fl.store.scatter",
-                                          rows=int(ids.size)):
+                                          rows=int(ids.size)) as span:
             # the read waits for the program that computes ``rows``: the
             # device is busy then, so idle time inside is the transfer
             with jax.profiler.TraceAnnotation("fl.store.to_host",
@@ -484,23 +597,25 @@ class CheckpointStore(ClientStateStore):
                 raise ValueError(
                     f"CheckpointStore.scatter: window shape {rows.shape} "
                     f"does not match ({ids.size}, {self.width})")
-            for i, c in enumerate(ids):
-                self._overlay[int(c)] = rows[i].copy()
+            span.set_metadata(new_rows=self._rows.write(ids, rows))
 
     def gather_residual(self, ids) -> jnp.ndarray:
         ids = self._check_ids(ids)
-        out = np.zeros((ids.size, self.width), np.float32)
-        for i, c in enumerate(ids):
-            row = self._residual_overlay.get(int(c))
-            if row is not None:
-                out[i] = row
-        return jnp.asarray(out)
+        slots = self._residual_rows.slot[ids]
+        with self._staging_lock:
+            out = self._stage(np.float32, ids.size)
+            out[slots < 0] = 0.0
+            self._residual_rows.read_into(out, slots)
+            return self._ship(out)
 
     def scatter_residual(self, ids, rows) -> None:
         ids = self._check_ids(ids)
         rows = np.asarray(rows, np.float32)
-        for i, c in enumerate(ids):
-            self._residual_overlay[int(c)] = rows[i].copy()
+        if rows.shape != (ids.size, self.width):
+            raise ValueError(
+                f"CheckpointStore.scatter_residual: window shape "
+                f"{rows.shape} does not match ({ids.size}, {self.width})")
+        self._residual_rows.write(ids, rows)
 
     def consensus(self) -> np.ndarray:
         """[width] mean over all enrolled rows without materializing them:
@@ -512,20 +627,23 @@ class CheckpointStore(ClientStateStore):
                 "consensus over a checkpoint-backed base requires a full "
                 "pass over the state file; hold a base row instead")
         acc = np.zeros((self.width,), np.float64)
-        for row in self._overlay.values():
-            acc += np.asarray(row, np.float64)
-        acc += (self.num_enrolled - len(self._overlay)) * np.asarray(
+        for s in self._rows.written()[1].tolist():
+            np.add(acc, self._rows.row(s), out=acc)
+        acc += (self.num_enrolled - len(self._rows)) * np.asarray(
             self._base_row, np.float64)
         return (acc / self.num_enrolled).astype(self.dtype)
 
     def save(self, ckpt_dir: str, step: int) -> str:
-        """Materialize overlay + base into one [D, width] checkpoint —
-        ONLY sensible at small D (tests, tier migration); at cold-tier D
+        """Materialize touched rows + base into one [D, width] checkpoint
+        — ONLY sensible at small D (tests, tier migration); at cold-tier D
         this would allocate the very buffer the tier exists to avoid."""
-        full = np.broadcast_to(self._base_row,
-                               (self.num_enrolled, self.width)).copy()
-        for c, row in self._overlay.items():
-            full[c] = row
+        full = np.empty((self.num_enrolled, self.width), self.dtype)
+        if self._base_row is not None:
+            full[...] = self._base_row
+        else:
+            load_leaves(self._base_path, np.arange(self.num_enrolled),
+                        out=[full])
+        self._rows.read_into(full, self._rows.slot)
         return save_checkpoint(ckpt_dir, step, {"state": full},
                                metadata={"num_enrolled": self.num_enrolled})
 
@@ -537,7 +655,7 @@ def make_store(base_row, num_enrolled: int, *, tier: str = "auto",
     """Build the right tier for D=``num_enrolled`` clients all starting at
     ``base_row`` ([sum(sizes)], the packed global init): a resident
     ``MemoryStore`` while [D, width] fits ``MEMORY_TIER_MAX_BYTES``, the
-    overlay-backed ``CheckpointStore`` beyond (where materializing the
+    arena-backed ``CheckpointStore`` beyond (where materializing the
     buffer is exactly the failure mode the store exists to remove)."""
     if tier not in ("auto", "memory", "checkpoint"):
         raise ValueError(f"unknown store tier {tier!r}; expected one of "

@@ -96,6 +96,31 @@ def test_load_leaves_restores_bf16_dtype(tmp_path):
         np.asarray(tree["b"]).view(np.uint16))
 
 
+def test_load_leaves_fills_caller_rows(tmp_path):
+    """``out``/``at``: requested rows land at the given positions of the
+    caller's arrays (bf16 included), other rows stay as they were; a
+    destination of the wrong row shape or dtype is refused."""
+    tree = _rowy_tree()
+    path = save_checkpoint(str(tmp_path), 5, tree)
+    idx, at = [3, 0, 11], [4, 1, 2]
+    b = np.full((6,), 7.0, np.float32).astype(tree["b"].dtype)
+    w = np.full((6, 5), 7.0, np.float32)
+    leaves, _ = load_leaves(path, idx, out=[b, w], at=at)
+    assert leaves[0] is b and leaves[1] is w
+    want_b = np.asarray(tree["b"], np.float32)
+    want_w = np.asarray(tree["w"])
+    np.testing.assert_array_equal(np.asarray(b, np.float32)[at], want_b[idx])
+    np.testing.assert_array_equal(w[at], want_w[idx])
+    np.testing.assert_array_equal(w[[0, 3, 5]], 7.0)
+    # only the first leaf has a destination: the second comes back fresh
+    leaves, _ = load_leaves(path, idx, out=[b], at=at)
+    np.testing.assert_array_equal(leaves[1], want_w[idx])
+    with pytest.raises(ValueError, match="C-contiguous rows"):
+        load_leaves(path, idx, out=[b, np.zeros((6, 4), np.float32)], at=at)
+    with pytest.raises(ValueError, match="at has shape"):
+        load_leaves(path, idx, out=[b], at=at[:2])
+
+
 def test_load_leaves_out_of_range_raises(tmp_path):
     path = save_checkpoint(str(tmp_path), 3, _rowy_tree())
     with pytest.raises(IndexError, match="out of range"):

@@ -69,9 +69,9 @@ def _store_state(se):
         if st._residual is not None:
             out["residual"] = np.asarray(st._residual)
     else:
-        out["overlay"] = {c: r.copy() for c, r in st._overlay.items()}
-        out["res_overlay"] = {c: r.copy()
-                              for c, r in st._residual_overlay.items()}
+        for name, residual in (("rows", False), ("res_rows", True)):
+            ids, rows = st.touched_rows(residual=residual)
+            out[name] = dict(zip(ids.tolist(), rows))
     return out
 
 
@@ -218,9 +218,9 @@ def test_checkpoint_prefetch_runs_on_background_thread():
 
 def test_checkpoint_prefetch_after_scatter_reads_post_scatter_rows(tmp_path):
     """Ordering pin for the fetch thread: a prefetch QUEUED behind the
-    worker when a conflicting scatter lands must observe the overlay row
-    (post-scatter), not the stale ``load_leaves`` base row — the overlay
-    is consulted per-id at fetch time."""
+    worker when a conflicting scatter lands must observe the written row
+    (post-scatter), not the stale ``load_leaves`` base row — the arena's
+    slots are read at fetch time."""
     from repro.checkpoint.io import save_checkpoint
     base = np.arange(16 * 4, dtype=np.float32).reshape(16, 4)
     path = save_checkpoint(str(tmp_path), 0, {"state": base})

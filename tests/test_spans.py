@@ -121,7 +121,12 @@ def test_sampled_span_counts(traced):
     width = eng.store.width
     gathers = [e[3] for e in ev if e[0] == "fl.store.gather"]
     assert all(g["rows"] == K and 0 <= g["cold_rows"] <= K for g in gathers)
-    assert [e[3] for e in ev if e[0] == "fl.store.scatter"] == [{"rows": K}] * T
+    scatters = [e[3] for e in ev if e[0] == "fl.store.scatter"]
+    assert [{"rows": s["rows"]} for s in scatters] == [{"rows": K}] * T
+    # the traced run repeats the first run's selections: every row it
+    # writes already has its slot
+    assert [s["new_rows"] for s in scatters] == [0] * T
+    assert eng.store.num_touched > 0
     for name in ("fl.store.to_device", "fl.store.to_host"):
         assert [e[3] for e in ev if e[0] == name] == [
             {"bytes": K * width * 4}] * T
