@@ -65,6 +65,17 @@ class ModelConfig:
     router_aux_loss_coef: float = 0.01
     first_dense_layers: int = 0      # deepseek: first k layers are dense
     moe_dense_d_ff: int = 0          # hidden size of those dense layers
+    # router: softmax (top-k of the softmax, renormalized) | sigmoid (top-k
+    # chosen on sigmoid + a selection-only bias, the router_bias leaf, and
+    # weighted by the sigmoid)
+    moe_router: str = "softmax"
+    norm_topk_prob: bool = True      # sigmoid router: weights normalized to sum 1
+    routed_scaling: float = 1.0      # sigmoid router: weights times this
+    shared_expert_d_ff: int = 0      # 0 => num_shared_experts * moe_d_ff
+    # experts the router scores (0 => num_experts). A layer holding a slice
+    # of the experts (one chip's share) keeps the published router width:
+    # it holds experts [0, num_experts) of router_experts.
+    router_experts: int = 0
 
     # --- SSM (Mamba-2 SSD) ---
     ssm_state: int = 0
@@ -72,6 +83,15 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_conv_width: int = 4
     ssm_chunk: int = 256
+    ssm_heads: int = 0               # 0 => ssm_expand * d_model // ssm_head_dim
+    ssm_n_groups: int = 1            # heads sharing one B and one C
+    ssm_norm_eps: float = 1e-6       # eps of the gated RMSNorm (per group)
+
+    # --- layer pattern (hybrid stacks such as Nemotron-H) ---
+    # one letter per block, each x + mixer(norm(x)): "M" Mamba-2, "E" MoE,
+    # "*" attention. "" => the family's homogeneous stack. Blocks are the
+    # first num_layers letters (a cut keeps the published pattern).
+    layer_pattern: str = ""
 
     # --- hybrid (hymba) ---
     num_meta_tokens: int = 0
@@ -94,6 +114,15 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def __post_init__(self):
+        if self.sliding_window is None:          # config.json's null
+            object.__setattr__(self, "sliding_window", 0)
+        if self.layer_pattern:
+            bad = set(self.layer_pattern) - set("ME*")
+            if bad or len(self.layer_pattern) < self.num_layers:
+                raise ValueError(
+                    f"ModelConfig {self.name}: layer_pattern needs one of "
+                    f"'M', 'E', '*' per layer for {self.num_layers} layers, "
+                    f"got {self.layer_pattern!r}")
         if self.num_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.num_heads and not self.num_kv_heads:
@@ -106,11 +135,22 @@ class ModelConfig:
 
     @property
     def d_inner_ssm(self) -> int:
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_num_heads(self) -> int:
         return self.d_inner_ssm // self.ssm_head_dim
+
+    @property
+    def pattern(self) -> str:
+        """The blocks this configuration builds ("" without a pattern)."""
+        return self.layer_pattern[:self.num_layers]
+
+    @property
+    def num_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
 
     def reduced(self, *, num_layers: int = 2, max_d_model: int = 256,
                 max_experts: int = 4, vocab: int = 512) -> "ModelConfig":
@@ -142,6 +182,9 @@ class ModelConfig:
                 num_shared_experts=min(self.num_shared_experts, 1),
                 moe_d_ff=max(8, int((self.moe_d_ff or self.d_ff) * scale)),
                 moe_dense_d_ff=max(8, int((self.moe_dense_d_ff or self.d_ff or 64) * scale)),
+                router_experts=ne if self.router_experts else 0,
+                shared_expert_d_ff=(max(8, int(self.shared_expert_d_ff * scale))
+                                    if self.shared_expert_d_ff else 0),
             )
         if self.use_mla:
             changes.update(kv_lora_rank=32, q_lora_rank=0,
@@ -149,6 +192,9 @@ class ModelConfig:
                            head_dim=24)
         if self.ssm_state:
             changes.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
+            if self.ssm_heads:
+                changes.update(ssm_heads=min(self.ssm_heads, 8),
+                               ssm_n_groups=min(self.ssm_n_groups, 2))
         if self.num_meta_tokens:
             changes.update(num_meta_tokens=8)
         if self.cross_attend:

@@ -12,6 +12,7 @@ from repro.configs import (
     hymba_1_5b,
     mamba2_130m,
     musicgen_medium,
+    nemotron_3_nano_30b_a3b,
     nemotron_4_15b,
     qwen2_1_5b,
     yi_34b,
@@ -22,6 +23,7 @@ from repro.configs.shapes import SHAPES
 _MODULES = (
     nemotron_4_15b, qwen2_1_5b, gemma_2b, yi_34b, dbrx_132b,
     musicgen_medium, mamba2_130m, chameleon_34b, deepseek_v2_236b, hymba_1_5b,
+    nemotron_3_nano_30b_a3b,
 )
 
 REGISTRY = {m.CONFIG.name: m.CONFIG for m in _MODULES}

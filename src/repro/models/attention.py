@@ -217,9 +217,15 @@ def blocked_attention(q, k, v, q_pos, kv_pos, window=0, num_meta=0,
                   q_block, k_block)
 
 
+#: the direct path's float32 score tensor may take at most this many bytes
+DIRECT_SCORE_BYTES = 2 ** 30
+
+
 def attention_core(q, k, v, q_pos, kv_pos, window=0, num_meta=0) -> jnp.ndarray:
-    """Static dispatch: blocked for long q, dense otherwise."""
-    if q.shape[1] >= 4096:
+    """Static dispatch: blocked for long q, or where the [B, H, Sq, Tk]
+    float32 scores would pass ``DIRECT_SCORE_BYTES``; dense otherwise."""
+    B, Sq, Hk, G, _ = q.shape
+    if Sq >= 4096 or 4 * B * Hk * G * Sq * k.shape[1] > DIRECT_SCORE_BYTES:
         return blocked_attention(q, k, v, q_pos, kv_pos, window, num_meta)
     return _direct_attention(q, k, v, q_pos, kv_pos, window, num_meta)
 

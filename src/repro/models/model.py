@@ -31,6 +31,10 @@ class Model:
     prefill: Callable
     decode: Callable
     make_cache: Callable
+    #: False where the loss holds an operation that takes no batch dimension
+    #: on the TPU (XLA's ragged dot, in the dropless MoE): engines then
+    #: train clients one after another, each device its own, not vmapped
+    client_vmap: bool = True
 
 
 def _forward_kwargs(cfg: ModelConfig, batch: Dict) -> Dict:
@@ -50,23 +54,28 @@ def build_model(cfg: ModelConfig) -> Model:
         return transformer.init_params(key, cfg, dtype)
 
     def loss_fn(params, batch, *, remat: bool = False):
+        """-> (loss, {"ce", "aux"[, "counters"]}); a layer-pattern model's
+        aux carries its blocks' counters (summed over the blocks)."""
         labels = batch["labels"]
         mask = batch.get("loss_mask")
         if mask is None:
             # fused chunked unembed+CE: full [B,S,V] f32 logits never exist
-            h, _, aux = transformer.forward(
+            h, _, aux, counters = transformer.forward(
                 params, cfg, cache=None, remat=remat, return_hidden=True,
-                **_forward_kwargs(cfg, batch))
+                with_counters=True, **_forward_kwargs(cfg, batch))
             heads = params.get("heads") if cfg.family == "audio" else None
             embed = params.get("embed")
             ce = chunked_cross_entropy(embed, h, labels, cfg, heads=heads)
         else:
-            logits, _, aux = transformer.forward(
-                params, cfg, cache=None, remat=remat,
+            logits, _, aux, counters = transformer.forward(
+                params, cfg, cache=None, remat=remat, with_counters=True,
                 **_forward_kwargs(cfg, batch))
             ce = cross_entropy(logits, labels, mask)
         loss = ce + aux
-        return loss, {"ce": ce, "aux": aux}
+        metrics = {"ce": ce, "aux": aux}
+        if counters:
+            metrics["counters"] = counters
+        return loss, metrics
 
     def make_cache(batch: int, buf_len: int, dtype=jnp.float32,
                    cross_len: int = 0):
@@ -84,4 +93,5 @@ def build_model(cfg: ModelConfig) -> Model:
         return logits[:, -1], cache
 
     return Model(cfg=cfg, init=init, loss_fn=loss_fn, prefill=prefill,
-                 decode=decode, make_cache=make_cache)
+                 decode=decode, make_cache=make_cache,
+                 client_vmap="E" not in cfg.pattern)

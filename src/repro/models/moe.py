@@ -9,6 +9,13 @@ results are gathered back per (token, k). Experts are sharded over the
 all-to-all-style collectives (a hillclimb target — see EXPERIMENTS.md §Perf).
 
 Covers DBRX (16e top-4) and DeepSeek-V2 (2 shared + 160 routed top-6).
+
+``moe_dropless`` is the layer of Nemotron-H's expert blocks: a sigmoid
+router with a selection-only bias, no capacity (every assignment to a held
+expert is computed, through ``jax.lax.ragged_dot`` over the assignments
+sorted by expert), and a layer that holds experts ``[offset, offset + E)``
+of the router's width: one chip's share under expert parallelism. Tokens
+routed to experts it does not hold add nothing here.
 """
 from __future__ import annotations
 
@@ -35,14 +42,16 @@ def init_moe(key, cfg: ModelConfig, dtype) -> Dict:
     ks = jax.random.split(key, 5)
     gated = cfg.mlp_variant in ("swiglu", "geglu")
     p = {
-        "router": dense_init(ks[0], d, (d, e), jnp.float32),
+        "router": dense_init(ks[0], d, (d, cfg.num_router_experts), jnp.float32),
         "w_in": dense_init(ks[1], d, (e, d, ff), dtype),
         "w_out": dense_init(ks[2], ff, (e, ff, d), dtype),
     }
+    if cfg.moe_router == "sigmoid":             # e_score_correction_bias
+        p["router_bias"] = jnp.zeros((cfg.num_router_experts,), jnp.float32)
     if gated:
         p["w_gate"] = dense_init(ks[3], d, (e, d, ff), dtype)
     if cfg.num_shared_experts:
-        shared_ff = ff * cfg.num_shared_experts
+        shared_ff = cfg.shared_expert_d_ff or ff * cfg.num_shared_experts
         import dataclasses
         shared_cfg = dataclasses.replace(cfg, mlp_bias=False)
         p["shared"] = init_mlp(ks[4], shared_cfg, d, shared_ff, dtype)
@@ -65,9 +74,11 @@ def _expert_ffn(p: Dict, xe: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", h, p["w_out"])
 
 
-def route(router_w: jnp.ndarray, x_flat: jnp.ndarray, cfg: ModelConfig
-          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def route(router_w: jnp.ndarray, x_flat: jnp.ndarray, cfg: ModelConfig,
+          bias=None) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Returns (weights [T,k], expert_idx [T,k] int32, aux_loss scalar)."""
+    if cfg.moe_router == "sigmoid":
+        return _route_sigmoid(router_w, x_flat, cfg, bias)
     logits = (x_flat.astype(jnp.float32) @ router_w)          # [T,E]
     probs = jax.nn.softmax(logits, axis=-1)
     weights, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
@@ -78,6 +89,82 @@ def route(router_w: jnp.ndarray, x_flat: jnp.ndarray, cfg: ModelConfig
     pbar = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(f * pbar) * cfg.router_aux_loss_coef
     return weights.astype(x_flat.dtype), idx.astype(jnp.int32), aux
+
+
+def _route_sigmoid(router_w, x_flat, cfg: ModelConfig, bias):
+    """DeepSeek-V3 / Nemotron-H routing: float32 logits (at full precision:
+    a single bfloat16 pass flips near-tied choices), sigmoid scores, the
+    top k chosen on ``scores + bias`` with the bias out of the gradient,
+    weighted by the plain scores, normalized to sum 1 when
+    ``norm_topk_prob``, then times ``routed_scaling``. No auxiliary loss."""
+    logits = jnp.dot(x_flat.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    weights = weights * cfg.routed_scaling
+    return weights, idx.astype(jnp.int32), jnp.zeros((), jnp.float32)
+
+
+def moe_dropless(p: Dict, x: jnp.ndarray, cfg: ModelConfig, *,
+                 offset: int = 0, shared: bool = True
+                 ) -> Tuple[jnp.ndarray, Dict]:
+    """x: [B,S,d] -> ([B,S,d], counters). The layer holds the experts of
+    ``p["w_in"]``, experts ``[offset, offset + E)`` of the router's width;
+    it routes over all of them and computes its own experts' part of the
+    result, plus the shared expert when ``shared``. Dropless: the T*k
+    assignments are sorted by expert (those to absent experts last) and
+    the held groups run through ``ragged_dot``; rows past the held groups
+    are masked out, so they add nothing in either direction.
+
+    counters: ``moe_assignments`` (assignments the held experts compute)
+    and ``moe_dropped`` (assignments routed to a held expert that the
+    ragged dots do not compute for it: 0, see ``computed_rows``)."""
+    if cfg.mlp_variant != "squared_relu":
+        raise NotImplementedError(
+            f"moe_dropless computes relu^2 experts, not {cfg.mlp_variant!r}")
+    B, S, d = x.shape
+    T, k = B * S, cfg.num_experts_per_tok
+    E = p["w_in"].shape[0]
+    x_flat = x.reshape(T, d)
+    weights, idx, _ = route(p["router"], x_flat, cfg, p.get("router_bias"))
+    local = idx.reshape(-1) - offset                          # [T*k]
+    held = (local >= 0) & (local < E)
+    group = jnp.where(held, local, E)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)[:E]
+    rows = jnp.arange(T * k) < jnp.sum(sizes)                 # held, sorted
+    xs = jnp.where(rows[:, None], x_flat[order // k], 0)    # token of each
+    with jax.named_scope("moe_experts"):
+        h = jnp.where(rows[:, None], jax.lax.ragged_dot(xs, p["w_in"], sizes), 0)
+        h = jnp.square(jax.nn.relu(h))
+        ys = jnp.where(rows[:, None], jax.lax.ragged_dot(h, p["w_out"], sizes), 0)
+    # back to (token, k) order by the inverse permutation, then weighted
+    unsort = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+    w = jnp.where(held, weights.reshape(-1), 0).astype(ys.dtype)
+    y = jnp.sum((ys[unsort] * w[:, None]).reshape(T, k, d), axis=1)
+    if shared and "shared" in p:
+        y = y + apply_mlp(p["shared"], x_flat, cfg)
+    done = computed_rows(group[order], sizes)
+    counters = {"moe_assignments": done,
+                "moe_dropped": jnp.sum(held.astype(jnp.int32)) - done}
+    return y.reshape(B, S, d), counters
+
+
+def computed_rows(sorted_group: jnp.ndarray, sizes: jnp.ndarray
+                  ) -> jnp.ndarray:
+    """How many of the sorted assignment rows the ragged dots compute for
+    the expert they were routed to. ``ragged_dot`` gives row r to the group
+    whose span of the cumulative ``sizes`` holds r; a row counts when that
+    group is its own held expert. A capacity cap on ``sizes``, or rows out
+    of expert order, leaves held rows uncounted."""
+    E = sizes.shape[0]
+    at = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(sorted_group.shape[0]),
+                          side="right", method="compare_all")
+    return jnp.sum(((at == sorted_group) & (sorted_group < E)).astype(jnp.int32))
 
 
 def dispatch_indices(idx: jnp.ndarray, num_experts: int, capacity: int

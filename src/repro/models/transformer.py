@@ -9,6 +9,13 @@ lower to compact HLO. Per-family block composition:
   ssm         : ssd mixer only                        (mamba2)
   hybrid      : (attn ∥ ssm, mean-combined) -> mlp    (hymba, + meta tokens)
 
+A ``layer_pattern`` (Nemotron-H) replaces the homogeneous stack: each block
+is ``x + mixer(RMSNorm(x))`` with one mixer, "M" (Mamba-2), "E" (dropless
+MoE) or "*" (attention), in the published order. Its parameters are
+stacked per run of equal blocks (``params["blocks"]``, one dict per run,
+leading dim the run's length), and each run is one scan: the gradient of a
+run's stack is formed whole, never padded into a larger stack.
+
 Caches for serving are stacked [L, ...] and scanned alongside params; ring
 /pinned-slot addressing is computed once per step at the top level.
 """
@@ -62,7 +69,39 @@ def _init_layer(key, cfg: ModelConfig, dtype, *, moe_layer: bool):
     return p
 
 
+#: the parameter key of each pattern block's mixer
+MIXERS = {"M": "ssm", "E": "moe", "*": "attn"}
+
+
+def pattern_runs(pattern: str) -> list:
+    """[(kind, length)] of the runs of equal blocks, in order."""
+    runs = []
+    for c in pattern:
+        if runs and runs[-1][0] == c:
+            runs[-1] = (c, runs[-1][1] + 1)
+        else:
+            runs.append((c, 1))
+    return runs
+
+
+def _init_block(key, cfg: ModelConfig, kind: str, dtype) -> Dict:
+    init = {"M": lambda k: ssm_mod.init_ssm(k, ssm_mod.ssm_dims(cfg), dtype),
+            "E": lambda k: moe_mod.init_moe(k, cfg, dtype),
+            "*": lambda k: attn_mod.init_attention(k, cfg, dtype)}[kind]
+    return {"ln1": init_norm(cfg, cfg.d_model, dtype), MIXERS[kind]: init(key)}
+
+
 def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
+    if cfg.layer_pattern:
+        k_embed, k_blocks = jax.random.split(key)
+        runs = pattern_runs(cfg.pattern)
+        run_keys = jax.random.split(k_blocks, len(runs))
+        return {
+            "embed": init_embed(k_embed, cfg, dtype),
+            "blocks": [jax.vmap(lambda k, c=c: _init_block(k, cfg, c, dtype))(
+                jax.random.split(rk, n)) for (c, n), rk in zip(runs, run_keys)],
+            "ln_f": init_norm(cfg, cfg.d_model, dtype),
+        }
     keys = jax.random.split(key, 4)
     params: Dict = {}
     if cfg.family != "audio":
@@ -110,6 +149,10 @@ def init_cache(cfg: ModelConfig, batch: int, buf_len: int, dtype,
         "index": jnp.zeros((), jnp.int32),
         "slot_pos": jnp.full((buf_len,), -1, jnp.int32),
     }
+    if cfg.layer_pattern:
+        cache["blocks"] = [_block_cache(cfg, c, n, batch, buf_len, dtype)
+                           for c, n in pattern_runs(cfg.pattern)]
+        return cache
     if cfg.family == "ssm" or cfg.family == "hybrid":
         dims = ssm_mod.ssm_dims(cfg)
         cache["conv"] = jnp.zeros((L, batch, dims.conv_width - 1, dims.conv_ch), dtype)
@@ -128,6 +171,20 @@ def init_cache(cfg: ModelConfig, batch: int, buf_len: int, dtype,
         cache["cross_k"] = jnp.zeros((L, batch, cross_len, hq, hd), dtype)
         cache["cross_v"] = jnp.zeros((L, batch, cross_len, hq, hd), dtype)
     return cache
+
+
+def _block_cache(cfg: ModelConfig, kind: str, n: int, batch: int,
+                 buf_len: int, dtype) -> Dict:
+    if kind == "M":
+        dims = ssm_mod.ssm_dims(cfg)
+        return {"conv": jnp.zeros((n, batch, dims.conv_width - 1, dims.conv_ch),
+                                  dtype),
+                "state": jnp.zeros((n, batch, dims.nheads, dims.headdim,
+                                    dims.nstate), jnp.float32)}
+    if kind == "*":
+        shape = (n, batch, buf_len, cfg.num_kv_heads, cfg.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {}
 
 
 _PER_LAYER_KEYS = ("k", "v", "latent", "k_rope", "conv", "state",
@@ -216,6 +273,51 @@ def _layer_forward(lp: Dict, x, bufs: Dict, cfg: ModelConfig, *,
     return x + y2, new_bufs, aux
 
 
+def _block_forward(kind: str, lp: Dict, x, bufs: Dict, cfg: ModelConfig, *,
+                   positions, kv_pos, write_slot):
+    """One pattern block, ``x + mixer(RMSNorm(x))``. Returns (x_out,
+    new_bufs, counters)."""
+    h = apply_norm(lp["ln1"], x, cfg)
+    if kind == "M":
+        with jax.named_scope("ssm"):
+            y, new = ssm_mod.ssm_mixer(lp["ssm"], h, ssm_mod.ssm_dims(cfg),
+                                       cache=bufs or None)
+        return x + y, new or {}, {}
+    if kind == "E":
+        with jax.named_scope("moe"):
+            y, counters = moe_mod.moe_dropless(lp["moe"], h, cfg)
+        return x + y, {}, counters
+    with jax.named_scope("attention"):
+        y, new_kv = attn_mod.attention(
+            lp["attn"], h, cfg, positions=positions,
+            kv_bufs=(bufs["k"], bufs["v"]) if bufs else None,
+            kv_pos=kv_pos, write_slot=write_slot)
+    return x + y, ({"k": new_kv[0], "v": new_kv[1]} if new_kv else {}), {}
+
+
+def _pattern_stack(blocks: list, x, block_bufs, cfg: ModelConfig, *, remat,
+                   positions, kv_pos, write_slot):
+    """Every run of equal blocks as one scan. Returns (x, [new bufs per
+    run], counters summed over the blocks)."""
+    totals: Dict = {}
+    new_bufs = []
+    for (kind, _), lp_run, bufs in zip(pattern_runs(cfg.pattern), blocks,
+                                       block_bufs):
+        def step(xc, xs, kind=kind):
+            lp, b = xs
+            out, nb, counts = _block_forward(
+                kind, lp, xc, b, cfg, positions=positions, kv_pos=kv_pos,
+                write_slot=write_slot)
+            return out, (nb, counts)
+
+        x, (nb, counts) = jax.lax.scan(
+            jax.checkpoint(step) if remat else step, x, (lp_run, bufs))
+        new_bufs.append(nb)
+        for name, v in counts.items():
+            totals[name] = totals.get(name, 0) + jnp.sum(v)
+    return x, new_bufs, totals
+
+
 # ---------------------------------------------------------------------------
 # Full forward
 # ---------------------------------------------------------------------------
@@ -227,9 +329,11 @@ def forward(params: Dict, cfg: ModelConfig, *,
             cache: Optional[Dict] = None,
             remat: bool = False,
             return_hidden: bool = False,
+            with_counters: bool = False,
             ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     """Returns (logits, new_cache, aux_loss) — or (hidden, ...) when
-    ``return_hidden`` (training uses the fused chunked unembed+CE instead).
+    ``return_hidden`` (training uses the fused chunked unembed+CE instead);
+    ``with_counters`` appends the blocks' counters (MoE assignments).
 
     Train: cache None. Prefill: fresh cache, S>1. Decode: cache, S==1.
     logits: [B,S,V] ([B,S,K,V] for audio); meta-token positions stripped.
@@ -265,10 +369,27 @@ def forward(params: Dict, cfg: ModelConfig, *,
         buf = cache["slot_pos"].shape[0]
         kv_pos = jnp.where(jnp.arange(buf) < S, jnp.arange(buf), -1).astype(jnp.int32)
 
+    aux_total = jnp.zeros((), jnp.float32)
+    counters: Dict = {}
+    if cfg.layer_pattern:
+        block_bufs = (cache["blocks"] if cache is not None
+                      else [{} for _ in pattern_runs(cfg.pattern)])
+        x, new_blocks, counters = _pattern_stack(
+            params["blocks"], x, block_bufs, cfg, remat=remat,
+            positions=positions, kv_pos=kv_pos, write_slot=write_slot)
+        if cache is not None:
+            new_cache = dict(cache, blocks=new_blocks, slot_pos=kv_pos,
+                             index=(cache["index"] + 1 if decode
+                                    else jnp.asarray(S, jnp.int32)))
+        x = apply_norm(params["ln_f"], x, cfg)
+        out = x if return_hidden else shard(
+            compute_logits(params["embed"], x, cfg), "act_btv")
+        return (out, new_cache, aux_total) + ((counters,) if with_counters
+                                               else ())
+
     fd = cfg.first_dense_layers
     head_bufs, tail_bufs = _split_cache(cache, fd)
     wins = layer_windows(cfg)
-    aux_total = jnp.zeros((), jnp.float32)
 
     def make_body(moe_layer: bool):
         def body(carry, xs):
@@ -311,7 +432,8 @@ def forward(params: Dict, cfg: ModelConfig, *,
         x = x[:, M:]
     x = apply_norm(params["ln_f"], x, cfg)
     if return_hidden:
-        return x, new_cache, aux_total
+        return (x, new_cache, aux_total) + ((counters,) if with_counters
+                                             else ())
 
     if cfg.family == "audio":
         logits = x @ params["heads"]
@@ -319,4 +441,5 @@ def forward(params: Dict, cfg: ModelConfig, *,
     else:
         logits = compute_logits(params["embed"], x, cfg)
     logits = shard(logits, "act_btv")
-    return logits, new_cache, aux_total
+    return (logits, new_cache, aux_total) + ((counters,) if with_counters
+                                             else ())

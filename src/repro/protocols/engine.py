@@ -1270,18 +1270,25 @@ class MeshEngine:
 
         @jax.named_scope("local_train")
         def local_train(params, batches):
+            """-> (params, mean loss, the loss's counters summed over the
+            steps; {} for a model without counters)."""
             def step(p, b):
-                (loss, _), grads = jax.value_and_grad(
+                (loss, aux), grads = jax.value_and_grad(
                     functools.partial(model.loss_fn, remat=remat),
                     has_aux=True)(p, b)
                 p = jax.tree.map(lambda w, g: (w - fl.lr * g.astype(jnp.float32)
                                                ).astype(w.dtype), p, grads)
-                return p, loss
+                return p, (loss, aux.get("counters", {}))
 
-            params, losses = jax.lax.scan(step, params, batches)
-            return params, jnp.mean(losses)
+            params, (losses, counters) = jax.lax.scan(step, params, batches)
+            return params, jnp.mean(losses), jax.tree.map(jnp.sum, counters)
 
-        self._vlocal = jax.vmap(local_train)
+        self._vlocal = (jax.vmap(local_train)
+                        if getattr(model, "client_vmap", True)
+                        else self._one_by_one(local_train))
+        #: the last ``run_rounds`` call's counters, {name: [T]} summed over
+        #: the clients (device arrays; {} for a model without counters)
+        self.counters = {}
 
         jit_kwargs = {"static_argnames": ("do_global_sync",)}
         if out_shardings is not None:
@@ -1305,6 +1312,22 @@ class MeshEngine:
         self.round_fn = jax.jit(self._round, **jit_kwargs)
         self._run_jit = jax.jit(self._run)
 
+    def _one_by_one(self, fn):
+        """Map ``fn`` over the client axis one client after another, for a
+        model that cannot be vmapped: on a mesh each device maps over its
+        own clients inside a shard_map, so no operation sees the client
+        axis."""
+        def mapped(params, batches):
+            return jax.lax.map(lambda pb: fn(*pb), (params, batches))
+        if self.mesh_info is None:
+            return mapped
+        from jax.sharding import PartitionSpec as P
+        ax = self.mesh_info.dp_axes
+        spec = P(ax if len(ax) > 1 else ax[0])
+        return jax.shard_map(mapped, mesh=self.mesh_info.mesh,
+                             in_specs=(spec, spec), out_specs=spec,
+                             check_vma=False)
+
     def _ctx(self, survive, key, round_index, do_global_sync: bool):
         return make_context(
             key=key, round_index=round_index, survive=survive,
@@ -1317,14 +1340,25 @@ class MeshEngine:
         return self.codec is not None and self.codec.stateful
 
     def _round(self, f_params, batches, survive, key,
-               do_global_sync: bool = True, round_index=0, codec_state=None):
+               do_global_sync: bool = True, round_index=0, codec_state=None,
+               with_counters: bool = False):
         """One mesh round. Stateless codecs ride ``ctx.codec`` into the
         protocol's ``psum_mix`` (the quantize/dequantize wire around the
         grouped psums). Stateful ones (error feedback) split the residual
         *here* — the engine owns cross-round state — by pre-transmitting
         f_new and handing ``psum_mix`` an already-on-the-wire tree with the
-        codec cleared; the return grows a third element (the residual)."""
-        f_new, losses = self._vlocal(f_params, batches)
+        codec cleared; the return grows a third element (the residual).
+        ``with_counters`` appends the round's counters, summed over the
+        clients."""
+        f_new, losses, counters = self._vlocal(f_params, batches)
+        out = self._mix_round(f_params, f_new, losses, survive, key,
+                              do_global_sync, round_index, codec_state)
+        if with_counters:
+            return out + (jax.tree.map(jnp.sum, counters),)
+        return out
+
+    def _mix_round(self, f_params, f_new, losses, survive, key,
+                   do_global_sync, round_index, codec_state):
         ctx = self._ctx(survive, key, round_index, bool(do_global_sync))
         if self.mesh_info is not None:
             if self._codec_stateful:
@@ -1372,12 +1406,12 @@ class MeshEngine:
             survive = straggler_mask(k_str, D, fl.straggler_rate)
             out = self._round(f_params, b, survive, k_mix,
                               do_global_sync=sync, round_index=t,
-                              codec_state=cstate)
+                              codec_state=cstate, with_counters=True)
             if stateful:
-                f_params, loss, cstate = out
+                f_params, loss, cstate, counts = out
             else:
-                f_params, loss = out
-            return f_params, key, loss, cstate
+                f_params, loss, counts = out
+            return f_params, key, (loss, counts), cstate
 
         def body(carry, xs):
             f_params, key, cstate = carry
@@ -1385,10 +1419,11 @@ class MeshEngine:
             out = []
             for i in range(sp):                      # unrolled: sync static
                 b_i = jax.tree.map(lambda leaf: leaf[i], chunk)
-                f_params, key, loss, cstate = one_round(
+                f_params, key, lc, cstate = one_round(
                     f_params, key, b_i, t0 + i, i == sp - 1, cstate)
-                out.append(loss)
-            return (f_params, key, cstate), jnp.stack(out)
+                out.append(lc)
+            return (f_params, key, cstate), jax.tree.map(
+                lambda *a: jnp.stack(a), *out)
 
         cstate = codec_state
         if stateful and cstate is None:
@@ -1397,21 +1432,25 @@ class MeshEngine:
             lambda x: x[:n_chunks * sp].reshape((n_chunks, sp) + x.shape[1:]),
             batches)
         t0s = jnp.arange(n_chunks, dtype=jnp.int32) * sp
-        (f_params, key, cstate), losses = jax.lax.scan(
+        (f_params, key, cstate), per_round = jax.lax.scan(
             body, (f_params, key, cstate), (main, t0s))
-        losses = losses.reshape((n_chunks * sp,))
+        per_round = jax.tree.map(lambda a: a.reshape((n_chunks * sp,)),
+                                 per_round)
         # T % sync_period tail rounds: never hit (t+1) % sp == 0 -> no sync
         tail = []
         for i in range(rem):
             b_i = jax.tree.map(lambda leaf: leaf[n_chunks * sp + i], batches)
-            f_params, key, loss, cstate = one_round(
+            f_params, key, lc, cstate = one_round(
                 f_params, key, b_i, n_chunks * sp + i, False, cstate)
-            tail.append(loss)
+            tail.append(lc)
         if tail:
-            losses = jnp.concatenate([losses, jnp.stack(tail)])
+            per_round = jax.tree.map(
+                lambda a, *t: jnp.concatenate([a, jnp.stack(t)]),
+                per_round, *tail)
+        losses, counters = per_round
         if stateful:
-            return f_params, losses, cstate
-        return f_params, losses
+            return f_params, losses, cstate, counters
+        return f_params, losses, counters
 
     def init_codec_state(self, f_params):
         """Zero error-feedback residual for stateful codecs (``None``
@@ -1432,15 +1471,19 @@ class MeshEngine:
 
         With a *stateful* codec (error feedback) the return grows a third
         element — the final residual — and ``codec_state`` seeds the scan
-        (zeros when None). Drivers that stage T in chunks (several
-        run_rounds calls per training run, e.g. ``launch.train``) MUST
-        thread it through, or every chunk boundary silently drops the
-        accumulated feedback mass."""
+        (zeros when None). The rounds' counters ({name: [T]}, summed over
+        the clients) are left in ``self.counters``. Drivers that stage T in
+        chunks (several run_rounds calls per training run, e.g.
+        ``launch.train``) MUST thread it through, or every chunk boundary
+        silently drops the accumulated feedback mass."""
         T = int(T)
         got = jax.tree.leaves(batches)[0].shape[0]
         if got != T:
             raise ValueError(f"batches carry {got} rounds, expected T={T}")
         with jax.profiler.TraceAnnotation("fl.run_rounds", rounds=T):
             if self._codec_stateful:
-                return self._run_jit(f_params, key, batches, codec_state)
-            return self._run_jit(f_params, key, batches)
+                *out, self.counters = self._run_jit(f_params, key, batches,
+                                                    codec_state)
+            else:
+                *out, self.counters = self._run_jit(f_params, key, batches)
+        return tuple(out)
